@@ -1044,7 +1044,7 @@ def place_schedule_inputs(params: Any, patches: jax.Array, mesh):
 
 def build_sharded_fn(sched: Schedule, params: Any, mesh, *, batch: int,
                      observer=None, preprocess=None, x_ndim: int = 3):
-    """Build the `shard_map` executor body for a model-axis mesh.
+    """Build the `shard_map` executor body for a serving mesh.
 
     Returns an UNJITTED ``fn(params, x) -> logits`` closure: the schedule
     replay wrapped in `shard_map` over the full mesh, with in_specs read
@@ -1055,12 +1055,15 @@ def build_sharded_fn(sched: Schedule, params: Any, mesh, *, batch: int,
     case: every data row computes identical logits while the model axis
     still splits the head grid.
 
-    Why not GSPMD for the model axis: the fused oracle's merged-QKV
+    Why not GSPMD: it cannot partition a Pallas (Mosaic) kernel at all,
+    so on the TPU even a 1-D data mesh must hand each device its own
+    rows; and on the model axis, the fused oracle's merged-QKV
     formulation (`kernels.ref._merge_qkv` — transpose+reshape+concat over
-    the head-sharded dim) is miscompiled by the XLA SPMD partitioner on
-    this jax generation (wrong VALUES, not an error), while the same
-    program under `shard_map` sees only local shards and never partitions
-    the reshape.  1-D data meshes keep the plain-GSPMD jit path.
+    the head-sharded dim) is miscompiled by the XLA SPMD partitioner
+    (wrong VALUES, not an error), while the same program under
+    `shard_map` sees only local shards and never partitions the reshape.
+    On a mesh with no ``model`` axis every weight replicates and no psum
+    fires.
 
     ``preprocess`` runs inside the shard_map body on the local batch rows
     before the replay (the server passes `vit.extract_patches` so images
@@ -1068,7 +1071,6 @@ def build_sharded_fn(sched: Schedule, params: Any, mesh, *, batch: int,
     its scales are host scalars closed over the body, replicated for
     free.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as shd
 
@@ -1090,36 +1092,31 @@ def build_sharded_fn(sched: Schedule, params: Any, mesh, *, batch: int,
             x = preprocess(x)
         return run_schedule(sched, p, x, observer=observer, shard=shard)
 
-    return shard_map(body, mesh=mesh, in_specs=(pspecs, x_spec),
-                     out_specs=P(bax, None), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, x_spec),
+                         out_specs=P(bax, None), check_vma=False)
 
 
 def run_schedule_sharded(sched: Schedule, params: Any, patches: jax.Array,
                          mesh, observer=None) -> jax.Array:
-    """`run_schedule`, distributed over a device mesh.
+    """`run_schedule`, distributed over a device mesh through
+    `build_sharded_fn`.
 
-    1-D ``("data",)`` meshes run the GSPMD jit path unchanged: every
-    phase — including the fused ``layer`` / ``inner_layer`` kernel chains
-    and the window/pixel folds, which only reshape *within* an image's
-    batch row — keeps the batch axis outermost-parallel, so one
-    `PartitionSpec` on the executor inputs shards the whole replay.
-
-    2-D ``("data", "model")`` meshes route through `build_sharded_fn`:
-    the head grid and MLP columns split over ``model`` under `shard_map`,
-    with explicit psums at the two residual re-entries.  int8 requires a
-    *frozen* calibrator either way (calibration itself is a host-side
-    amax loop and stays single-device).
+    On a 1-D ``("data",)`` mesh each device replays the schedule on its
+    batch rows: every phase — including the fused ``layer`` /
+    ``inner_layer`` kernel chains and the window/pixel folds, which only
+    reshape *within* an image's batch row — is independent per image.
+    On a 2-D ``("data", "model")`` mesh the head grid and MLP columns
+    additionally split over ``model``, with explicit psums at the two
+    residual re-entries.  int8 requires a *frozen* calibrator either way
+    (calibration itself is a host-side amax loop and stays
+    single-device).
 
     Serving keeps its own per-bucket jit cache (`VisionServer`); this
     entry compiles per call and is meant for tests and one-shot runs.
     """
     assert observer is None or observer.frozen is not None, \
         "sharded execution needs frozen calibration scales (or float mode)"
-    from repro.distributed import sharding as shd
     params, patches = place_schedule_inputs(params, patches, mesh)
-    if shd.axis_size(mesh, "model") > 1:
-        fn = build_sharded_fn(sched, params, mesh,
-                              batch=patches.shape[0], observer=observer)
-        return jax.jit(fn)(params, patches)
-    fwd = jax.jit(lambda p, x: run_schedule(sched, p, x, observer=observer))
-    return fwd(params, patches)
+    fn = build_sharded_fn(sched, params, mesh, batch=patches.shape[0],
+                          observer=observer)
+    return jax.jit(fn)(params, patches)

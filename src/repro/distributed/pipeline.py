@@ -23,7 +23,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -67,10 +66,10 @@ def pipeline_apply(stage_fn: Callable, stacked_params: Any,
 
     pspec = jax.tree_util.tree_map(
         lambda a: P(*((axis,) + (None,) * (a.ndim - 1))), stacked_params)
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(pspec, P(*((None,) * microbatches.ndim))),
-                   out_specs=P(axis, *((None,) * microbatches.ndim)),
-                   check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(pspec, P(*((None,) * microbatches.ndim))),
+                       out_specs=P(axis, *((None,) * microbatches.ndim)),
+                       check_vma=False)
     outs = fn(stacked_params, microbatches)
     return outs[-1]   # the last stage's collected outputs
 

@@ -30,21 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.config import ModelConfig
 
 
-def abstract_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """`jax.sharding.AbstractMesh` across API generations.
-
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    ``((name, size), ...)`` shape tuple — passing the new calling
-    convention there puts the int sizes where name/size pairs are expected
-    and dies with ``TypeError: 'int' object is not iterable``.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, shape)))
-
-
 # name -> (spec for the *unstacked* shape); "M" = model axis placeholder
 _COL = ("wq", "wk", "wv", "w_up", "w_gate", "w_x", "w_gate_branch",
         "w_in", "w_z", "w_q", "w_k", "w_v", "w_input_gate", "w_rec_gate",

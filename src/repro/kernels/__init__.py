@@ -12,12 +12,12 @@ Kernels (each with a pure-jnp oracle in ref.py, validated in interpret mode):
 `ops` is the backend-dispatching public surface used by model code.
 """
 
-from . import compat, ops, ref
+from . import ops, ref
 from .fused_mlp import fused_mlp
 from .head_attention import decode_attention, flash_attention
 from .int8_matmul import int8_matmul
 from .vita_msa import vita_msa, vita_msa_batched, vita_msa_int8
 
-__all__ = ["compat", "ops", "ref", "fused_mlp", "flash_attention",
+__all__ = ["ops", "ref", "fused_mlp", "flash_attention",
            "decode_attention", "int8_matmul", "vita_msa",
            "vita_msa_batched", "vita_msa_int8"]

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 
 from .ref import act_fn
 
@@ -125,7 +124,7 @@ def fused_mlp(x: jax.Array, w1: jax.Array, w2: jax.Array,
         out_specs=pl.BlockSpec((bn, d_out), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bn, d_out), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 NEG_INF = -1e30
 
 
@@ -117,7 +115,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -206,7 +204,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((group,), jnp.float32),
             pltpu.VMEM((group, dh), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, k_cache, v_cache, lengths)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 
 def _int8_mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
                     n_kblocks: int, scaled: bool):
@@ -102,7 +100,7 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -1,10 +1,12 @@
 """Backend dispatch for the compute hot-spots.
 
 Every op has two implementations that compute the same math:
-  * ``xla``    — pure jnp (ref.py oracles).  Used on CPU, for dry-run
-                 lowering (cost_analysis sees real FLOPs) and as fallback.
-  * ``pallas`` — the TPU kernels (interpret=True off-TPU, so CPU tests
-                 execute the actual kernel bodies).
+  * ``xla``    — pure jnp (ref.py oracles): the reference the kernels are
+                 checked against, and the path for dry-run lowering
+                 (cost_analysis sees real FLOPs).
+  * ``pallas`` — the TPU kernels: compiled on the TPU, interpreted on the
+                 CPU (so CPU tests execute the actual kernel bodies), and
+                 refused on any other platform.
 
 Model code calls these entry points; `set_backend` / the ``backend=`` kwarg
 selects the path.  Kernel block sizes are chosen here from the shapes
@@ -55,7 +57,18 @@ def get_backend(override: Optional[str] = None) -> str:
 
 
 def _interp() -> bool:
-    return not on_tpu()
+    """Whether to interpret the Pallas kernels: on the CPU only.  On the
+    TPU they compile; anywhere else they cannot run, and saying so beats
+    silently interpreting them on an accelerator."""
+    if on_tpu():
+        return False
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"the Pallas kernels compile for the TPU and are interpreted "
+            f"on the CPU; platform {platform!r} can run neither "
+            f"(use backend='xla')")
+    return True
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):

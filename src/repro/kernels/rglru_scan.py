@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 
 def _chunk_scan(a: jax.Array, b: jax.Array, h0: jax.Array):
     """In-VMEM log-depth scan: h_t = a_t h_{t-1} + b_t over chunk rows.
@@ -82,7 +80,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         out_specs=pl.BlockSpec((1, ch, w), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, t, w), a.dtype),
         scratch_shapes=[pltpu.VMEM((w,), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

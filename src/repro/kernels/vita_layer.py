@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 # Shared single definitions: the LN math (also behind `ops.layer_norm`)
 # and the engine-2 softmax·V core of the per-phase MSA kernels.
 from .ref import layer_norm_ref as _ln
@@ -179,7 +178,7 @@ def vita_layer(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, n, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, d), jnp.float32),   # z (stationary)
                         pltpu.VMEM((n, d), jnp.float32)],  # concat acc
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -188,6 +187,18 @@ def vita_layer(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
 # ---------------------------------------------------------------------------
 # layer-group megakernel (float): L stacked layers, one pallas_call
 # ---------------------------------------------------------------------------
+
+
+def _layer_vec_spec(width: int) -> pl.BlockSpec:
+    """Layer l's row of an (L, 1, width) per-layer vector stack.  The unit
+    middle axis keeps the block's last two dims whole, as the TPU tiling
+    rule asks of a block (a (1, width) block of (L, width) is refused)."""
+    return pl.BlockSpec((1, 1, width), lambda i, l, j: (l, 0, 0))
+
+
+def _layer_vecs(*vecs):
+    """(L, width) per-layer vectors -> the (L, 1, width) operand layout."""
+    return [v.reshape(v.shape[0], 1, -1) for v in vecs]
 
 
 def _vita_layer_group_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
@@ -274,19 +285,20 @@ def vita_layer_group(x: jax.Array, wq: jax.Array, wk: jax.Array,
     m = w_up.shape[2]
     wmsa_h = w_msa.reshape(n_l, h, dh, d)  # head-major concat slices
     w_spec = pl.BlockSpec((1, 1, d, dh), lambda i, l, j: (l, j, 0, 0))
-    vec_d = pl.BlockSpec((1, d), lambda i, l, j: (l, 0))
+    vec_d, vec_m = _layer_vec_spec(d), _layer_vec_spec(m)
     in_specs = [
         pl.BlockSpec((1, n, d), lambda i, l, j: (i, 0, 0)),   # x (l==0 only)
         w_spec, w_spec, w_spec,
         pl.BlockSpec((1, 1, dh, d), lambda i, l, j: (l, j, 0, 0)),
         vec_d, vec_d, vec_d, vec_d,
         pl.BlockSpec((1, d, m), lambda i, l, j: (l, 0, 0)),   # w_up[l]
-        pl.BlockSpec((1, m), lambda i, l, j: (l, 0)),
+        vec_m,
         pl.BlockSpec((1, m, d), lambda i, l, j: (l, 0, 0)),   # w_down[l]
         vec_d,
     ]
-    operands = [x, wq, wk, wv, wmsa_h, ln1_w, ln1_b, ln2_w, ln2_b,
-                w_up, b_up, w_down, b_down]
+    operands = [x, wq, wk, wv, wmsa_h,
+                *_layer_vecs(ln1_w, ln1_b, ln2_w, ln2_b),
+                w_up, *_layer_vecs(b_up), w_down, *_layer_vecs(b_down)]
     windowed = bias is not None
     if windowed:
         n_w = mask.shape[0]
@@ -306,7 +318,7 @@ def vita_layer_group(x: jax.Array, wq: jax.Array, wk: jax.Array,
         scratch_shapes=[pltpu.VMEM((n, d), jnp.float32),   # y (carry)
                         pltpu.VMEM((n, d), jnp.float32),   # z (stationary)
                         pltpu.VMEM((n, d), jnp.float32)],  # concat acc
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -397,7 +409,9 @@ def vita_layer_int8(x: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
     wmsa_h = wmsa_q.reshape(h, dh, d)
     act_scales = jnp.asarray(act_scales, jnp.float32).reshape(1, 4)
     w_spec = pl.BlockSpec((1, d, dh), lambda i, j: (j, 0, 0))
-    s_spec = pl.BlockSpec((1, dh), lambda i, j: (j, 0))
+    # Per-head scales ride as (H, 1, Dh) so a head's block keeps its last
+    # two dims whole (the TPU tiling rule).
+    s_spec = pl.BlockSpec((1, 1, dh), lambda i, j: (j, 0, 0))
     vec_d = pl.BlockSpec((d,), lambda i, j: (0,))
     vec_m = pl.BlockSpec((m,), lambda i, j: (0,))
     in_specs = [
@@ -411,8 +425,8 @@ def vita_layer_int8(x: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
         pl.BlockSpec((m, d), lambda i, j: (0, 0)), vec_d, vec_d,
     ]
     operands = [x, wq_q, wk_q, wv_q, wmsa_h, act_scales,
-                wq_scale.astype(jnp.float32), wk_scale.astype(jnp.float32),
-                wv_scale.astype(jnp.float32),
+                *(ws.astype(jnp.float32).reshape(h, 1, dh)
+                  for ws in (wq_scale, wk_scale, wv_scale)),
                 wmsa_scale.astype(jnp.float32).reshape(d),
                 ln1_w, ln1_b, ln2_w, ln2_b,
                 wup_q, wup_scale.astype(jnp.float32).reshape(m), b_up,
@@ -435,7 +449,7 @@ def vita_layer_int8(x: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, d), jnp.int8),      # zq (stationary)
                         pltpu.VMEM((n, d), jnp.int32)],    # concat acc
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -462,8 +476,8 @@ def _vita_layer_group_int8_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
         extra = None
     l = pl.program_id(1)
     j = pl.program_id(2)
-    s_qkv = acts_ref[0, 0]
-    s_msa = acts_ref[0, 1]
+    s_qkv = acts_ref[0, 0, 0]
+    s_msa = acts_ref[0, 0, 1]
 
     @pl.when((l == 0) & (j == 0))
     def _load():
@@ -471,8 +485,8 @@ def _vita_layer_group_int8_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
 
     @pl.when(j == 0)
     def _init():
-        # Each layer requantizes at ITS frozen per-site scale (the (1, 4)
-        # acts block is indexed by the layer axis).
+        # Each layer requantizes at ITS frozen per-site scale (the
+        # (1, 1, 4) acts block is indexed by the layer axis).
         zq_ref[...] = _quant(_ln(y_ref[...], ln1w_ref[0], ln1b_ref[0]),
                              s_qkv)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -489,8 +503,8 @@ def _vita_layer_group_int8_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
 
     @pl.when(j == n_heads - 1)
     def _tail():
-        s_up = acts_ref[0, 2]
-        s_down = acts_ref[0, 3]
+        s_up = acts_ref[0, 0, 2]
+        s_down = acts_ref[0, 0, 3]
         msa_out = acc_ref[...].astype(jnp.float32) * (s_msa * msas_ref[0])
         h1 = y_ref[...] + msa_out
         z2q = _quant(_ln(h1, ln2w_ref[0], ln2b_ref[0]), s_up)
@@ -535,29 +549,33 @@ def vita_layer_group_int8(x: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
     n_l, h, _, dh = wq_q.shape
     m = wup_q.shape[2]
     wmsa_h = wmsa_q.reshape(n_l, h, dh, d)
-    act_scales = jnp.asarray(act_scales, jnp.float32).reshape(n_l, 4)
+    act_scales = jnp.asarray(act_scales, jnp.float32).reshape(n_l, 1, 4)
     w_spec = pl.BlockSpec((1, 1, d, dh), lambda i, l, j: (l, j, 0, 0))
-    s_spec = pl.BlockSpec((1, 1, dh), lambda i, l, j: (l, j, 0))
-    vec_d = pl.BlockSpec((1, d), lambda i, l, j: (l, 0))
-    vec_m = pl.BlockSpec((1, m), lambda i, l, j: (l, 0))
+    # (L, H, 1, Dh) per-head scales and (L, 1, X) per-layer vectors: the
+    # unit axes keep every block's last two dims whole (TPU tiling rule).
+    s_spec = pl.BlockSpec((1, 1, 1, dh), lambda i, l, j: (l, j, 0, 0))
+    vec_d, vec_m = _layer_vec_spec(d), _layer_vec_spec(m)
     in_specs = [
         pl.BlockSpec((1, n, d), lambda i, l, j: (i, 0, 0)),   # x (l==0 only)
         w_spec, w_spec, w_spec,
         pl.BlockSpec((1, 1, dh, d), lambda i, l, j: (l, j, 0, 0)),
-        pl.BlockSpec((1, 4), lambda i, l, j: (l, 0)),         # act scales[l]
+        _layer_vec_spec(4),                                   # act scales[l]
         s_spec, s_spec, s_spec, vec_d,
         vec_d, vec_d, vec_d, vec_d,
         pl.BlockSpec((1, d, m), lambda i, l, j: (l, 0, 0)), vec_m, vec_m,
         pl.BlockSpec((1, m, d), lambda i, l, j: (l, 0, 0)), vec_d, vec_d,
     ]
+    f32 = jnp.float32
     operands = [x, wq_q, wk_q, wv_q, wmsa_h, act_scales,
-                wq_scale.astype(jnp.float32), wk_scale.astype(jnp.float32),
-                wv_scale.astype(jnp.float32),
-                wmsa_scale.astype(jnp.float32).reshape(n_l, d),
-                ln1_w, ln1_b, ln2_w, ln2_b,
-                wup_q, wup_scale.astype(jnp.float32).reshape(n_l, m), b_up,
-                wdown_q, wdown_scale.astype(jnp.float32).reshape(n_l, d),
-                b_down]
+                *(ws.astype(f32).reshape(n_l, h, 1, dh)
+                  for ws in (wq_scale, wk_scale, wv_scale)),
+                *_layer_vecs(wmsa_scale.astype(f32).reshape(n_l, d),
+                             ln1_w, ln1_b, ln2_w, ln2_b),
+                wup_q,
+                *_layer_vecs(wup_scale.astype(f32).reshape(n_l, m), b_up),
+                wdown_q,
+                *_layer_vecs(wdown_scale.astype(f32).reshape(n_l, d),
+                             b_down)]
     windowed = bias is not None
     if windowed:
         n_w = mask.shape[0]
@@ -578,7 +596,7 @@ def vita_layer_group_int8(x: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
         scratch_shapes=[pltpu.VMEM((n, d), jnp.float32),   # y (carry)
                         pltpu.VMEM((n, d), jnp.int8),      # zq (stationary)
                         pltpu.VMEM((n, d), jnp.int32)],    # concat acc
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)

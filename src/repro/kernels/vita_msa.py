@@ -47,51 +47,67 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 (compat)
+from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
+
+def f32_dot(a, b, *, interpret: bool = False):
+    """MXU matmul with an f32 accumulator.  The CPU interpreter has no
+    bf16 x bf16 -> f32 dot, so when interpreting, bf16 operands widen to
+    f32 first; that is exact (a bf16 product fits in f32's mantissa), and
+    the chip still multiplies bf16 natively."""
+    if interpret:
+        a, b = (x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x
+                for x in (a, b))
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
 def softmax_av(q, k, v, *, scale: float, out_dtype=jnp.float32,
-               extra=None):
+               extra=None, interpret: bool = False):
     """Engine 2 core: QK^T (PE block 4) -> stable softmax -> S.V (PE
     block 5).  The one in-kernel definition — `vita_layer` imports it."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = f32_dot(q, k.T, interpret=interpret) * scale
     if extra is not None:
         s = s + extra
     s = s - jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
-    return jnp.dot(p.astype(out_dtype), v.astype(out_dtype),
-                   preferred_element_type=jnp.float32)
+    return f32_dot(p.astype(out_dtype), v.astype(out_dtype),
+                   interpret=interpret)
 
 
-def _attend(q, k, v, o_ref, *, scale: float, out_dtype, extra=None):
+def _attend(q, k, v, o_ref, *, scale: float, out_dtype, extra=None,
+            interpret: bool = False):
     o_ref[0, 0] = softmax_av(q, k, v, scale=scale, out_dtype=out_dtype,
-                             extra=extra).astype(o_ref.dtype)
+                             extra=extra, interpret=interpret
+                             ).astype(o_ref.dtype)
 
 
 def _vita_msa_kernel(z_ref, wq_ref, wk_ref, wv_ref, *rest, scale: float,
-                     windowed: bool, has_qkv_bias: bool):
+                     windowed: bool, has_qkv_bias: bool, interpret: bool):
     rest = list(rest)
     o_ref = rest.pop()
-    qb = rest.pop(0)[:, 0] if has_qkv_bias else None       # (3, Dh)
+    qb = rest.pop(0)[:, 0] if has_qkv_bias else None       # (3, 1, Dh)
     extra = rest[0][0] + rest[1][0] if windowed else None
     z = z_ref[0]
     # Engine 1: per-head projections (PE blocks 1-3).
-    q = jnp.dot(z, wq_ref[0], preferred_element_type=jnp.float32)
-    k = jnp.dot(z, wk_ref[0], preferred_element_type=jnp.float32)
-    v = jnp.dot(z, wv_ref[0], preferred_element_type=jnp.float32)
+    q = f32_dot(z, wq_ref[0], interpret=interpret)
+    k = f32_dot(z, wk_ref[0], interpret=interpret)
+    v = f32_dot(z, wv_ref[0], interpret=interpret)
     if qb is not None:
         q = q + qb[0]
         k = k + qb[1]
         v = v + qb[2]
-    _attend(q, k, v, o_ref, scale=scale, out_dtype=z.dtype, extra=extra)
+    _attend(q, k, v, o_ref, scale=scale, out_dtype=z.dtype, extra=extra,
+            interpret=interpret)
 
 
-def _qkv_bias_spec(dh: int) -> pl.BlockSpec:
-    """(3, H, Dh) stacked per-head Q/K/V bias, selected by head index."""
-    return pl.BlockSpec((3, 1, dh), lambda i, j: (0, j, 0))
+def _qkv_bias_operand(qkv_bias: jax.Array):
+    """(3, H, Dh) stacked per-head Q/K/V bias -> a (3, H, 1, Dh) operand
+    whose per-head block (3, 1, 1, Dh) keeps its last two dims whole, as
+    the TPU tiling rule asks; selected by head index."""
+    three, h, dh = qkv_bias.shape
+    spec = pl.BlockSpec((three, 1, 1, dh), lambda i, j: (0, j, 0, 0))
+    return spec, qkv_bias.astype(jnp.float32).reshape(three, h, 1, dh)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -123,8 +139,9 @@ def vita_msa_batched(z: jax.Array, wq: jax.Array, wk: jax.Array,
     in_specs = [z_spec, w_spec, w_spec, w_spec]
     operands = [z, wq, wk, wv]
     if qkv_bias is not None:
-        in_specs.append(_qkv_bias_spec(dh))
-        operands.append(qkv_bias.astype(jnp.float32))
+        spec, qkv_bias = _qkv_bias_operand(qkv_bias)
+        in_specs.append(spec)
+        operands.append(qkv_bias)
     if bias is not None:
         n_w = mask.shape[0]
         in_specs += [
@@ -134,14 +151,15 @@ def vita_msa_batched(z: jax.Array, wq: jax.Array, wk: jax.Array,
         operands += [bias.astype(jnp.float32), mask.astype(jnp.float32)]
     kernel = functools.partial(_vita_msa_kernel, scale=dh ** -0.5,
                                windowed=bias is not None,
-                               has_qkv_bias=qkv_bias is not None)
+                               has_qkv_bias=qkv_bias is not None,
+                               interpret=interpret)
     return pl.pallas_call(
         kernel,
         grid=(b, h),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, n, dh), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, n, dh), z.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -175,7 +193,7 @@ def _vita_msa_int8_kernel(z_ref, wq_ref, wk_ref, wv_ref, xs_ref,
                           windowed: bool, has_qkv_bias: bool):
     rest = list(rest)
     o_ref = rest.pop()
-    qb = rest.pop(0)[:, 0] if has_qkv_bias else None       # (3, Dh) fp32
+    qb = rest.pop(0)[:, 0] if has_qkv_bias else None    # (3, 1, Dh) fp32
     extra = rest[0][0] + rest[1][0] if windowed else None
     z = z_ref[0]                         # (N, D) int8
     xs = xs_ref[0, 0]                    # per-tensor activation scale
@@ -215,19 +233,22 @@ def vita_msa_int8(z_q: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
     h, _, dh = wq_q.shape
     x_scale = jnp.asarray(x_scale, jnp.float32).reshape(1, 1)
     w_spec = pl.BlockSpec((1, d, dh), lambda i, j: (j, 0, 0))
-    s_spec = pl.BlockSpec((1, dh), lambda i, j: (j, 0))
+    # Per-head scales ride as (H, 1, Dh): a (1, 1, Dh) block keeps the
+    # last two dims whole, which the TPU tiling rule asks of a block.
+    s_spec = pl.BlockSpec((1, 1, dh), lambda i, j: (j, 0, 0))
     in_specs = [
         pl.BlockSpec((1, n, d), lambda i, j: (i, 0, 0)),       # z stationary
         w_spec, w_spec, w_spec,
         pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         s_spec, s_spec, s_spec,
     ]
-    operands = [z_q, wq_q, wk_q, wv_q, x_scale,
-                wq_scale.astype(jnp.float32), wk_scale.astype(jnp.float32),
-                wv_scale.astype(jnp.float32)]
+    operands = [z_q, wq_q, wk_q, wv_q, x_scale] + [
+        ws.astype(jnp.float32).reshape(h, 1, dh)
+        for ws in (wq_scale, wk_scale, wv_scale)]
     if qkv_bias is not None:
-        in_specs.append(_qkv_bias_spec(dh))
-        operands.append(qkv_bias.astype(jnp.float32))
+        spec, qkv_bias = _qkv_bias_operand(qkv_bias)
+        in_specs.append(spec)
+        operands.append(qkv_bias)
     if bias is not None:
         n_w = mask.shape[0]
         in_specs += [
@@ -244,7 +265,7 @@ def vita_msa_int8(z_q: jax.Array, wq_q: jax.Array, wk_q: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, n, dh), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, n, dh), jnp.float32),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -291,8 +291,6 @@ def lower_cell(arch: str, shape: str, mesh, *, variant: str = "",
     t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

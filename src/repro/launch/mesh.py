@@ -13,24 +13,15 @@ from typing import Optional, Sequence
 
 import numpy as np
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str], *,
-                     devices=None) -> Mesh:
-    """`jax.make_mesh` across API generations.
-
-    jax >= 0.5 takes ``axis_types`` (we want every axis Auto, the default
-    sharding-in-types behaviour); 0.4.x has neither the kwarg nor the
-    ``jax.sharding.AxisType`` enum — there, plain `make_mesh` already gives
-    the equivalent untyped mesh.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), devices=devices,
-            axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis ``Auto`` (the compiler propagates
+    shardings; `jax.make_mesh` alone makes them ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -42,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devices)}; "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return make_mesh_compat(shape, axes, devices=devices[:need])
+    return make_mesh(shape, axes, devices=devices[:need])
 
 
 def make_debug_mesh(*, multi_pod: bool = False, model: int = 2,
@@ -51,7 +42,7 @@ def make_debug_mesh(*, multi_pod: bool = False, model: int = 2,
     shape = (2, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     need = int(np.prod(shape))
-    return make_mesh_compat(shape, axes, devices=jax.devices()[:need])
+    return make_mesh(shape, axes, devices=jax.devices()[:need])
 
 
 def parse_mesh_shape(text) -> tuple:
@@ -95,9 +86,9 @@ def make_vision_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
             f"{len(devices)}; on CPU run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
     if model == 1:
-        return make_mesh_compat((data,), ("data",), devices=devices[:need])
-    return make_mesh_compat((data, model), ("data", "model"),
-                            devices=devices[:need])
+        return make_mesh((data,), ("data",), devices=devices[:need])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=devices[:need])
 
 
 # TPU v5e hardware constants used by the roofline analysis.

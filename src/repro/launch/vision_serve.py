@@ -27,10 +27,11 @@ Buckets round up to a multiple of the data-axis size so every padded
 micro-batch lands pre-sharded before the one jitted call.
 ``mesh_shape=`` / ``--mesh DxM`` instead builds the 2-D
 ``("data", "model")`` latency mesh: the batch still rides ``data`` while
-the per-head QKV stacks and MLP columns split over ``model`` and the
-drain runs under `shard_map` with explicit all-reduces
-(`core.schedule.build_sharded_fn`) — so a batch=1 request engages every
-device of the model axis instead of one.
+the per-head QKV stacks and MLP columns split over ``model`` with
+explicit all-reduces — so a batch=1 request engages every device of the
+model axis instead of one.  Either mesh runs the drain under `shard_map`
+(`core.schedule.build_sharded_fn`), so each device runs the kernels on
+its own shard: GSPMD cannot partition a Pallas kernel.
 
 Fusion is policy-driven per batch bucket: ``--fusion-policy
 {always,never,auto}`` (`core.schedule.FusionPolicy`), where ``auto``
@@ -44,7 +45,7 @@ measured data).  ``--profile`` runs the per-phase HUE
 profiler after each mode's drain (`VisionServer.profile_stats`,
 docs/PROFILING.md) and prints the measured-vs-modelled table.
 
-Usage (CPU examples):
+Usage (CPU examples; on the TPU drop the XLA_FLAGS device faking):
   PYTHONPATH=src python -m repro.launch.serve --vision --list-models
   PYTHONPATH=src python -m repro.launch.serve --vision --model swin_t \
       --requests 32 --buckets 1,2,4,8 --mode both
@@ -75,6 +76,7 @@ from repro.core import schedule as sched_lib
 from repro.core.quant import Calibrator
 from repro.core.schedule import FusionPolicy
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vision_registry, vit
 
 
@@ -247,7 +249,8 @@ class VisionServer:
     turn on data-parallel drains: params/qparams are placed replicated,
     buckets round up to a multiple of the data-axis size, and every padded
     micro-batch is device_put pre-sharded on ``data`` before the one
-    jitted call — GSPMD splits the whole `(batch, head)` grid, fused or
+    jitted call, which runs the replay under `shard_map` — each device
+    runs the `(batch, head)` grid kernels on its own rows, fused or
     unfused, float or int8 (the frozen calibration scales are scalars and
     replicate as jit constants).
 
@@ -367,55 +370,78 @@ class VisionServer:
         The join key bench rows / compare_bench / HUE reports carry."""
         return f"{self.dp}x{self.mp}"
 
+    @property
+    def served_params(self):
+        """The param tree this server's mode runs on (int8: quantized)."""
+        return self.qparams if self.mode == "int8" else self.params
+
     def _forward_for(self, fused: bool, group: int = 1,
                      bucket: Optional[int] = None):
-        """The jitted batched forward for one (fusion, group-size) variant
-        (built lazily — a policy that never flips serves exactly one).
-        jit's own shape-keyed cache gives one compiled program per
-        bucket.  On a model-axis mesh the variant key also carries the
-        bucket's data-divisibility: `build_sharded_fn` fixes the batch
-        PartitionSpec (sharded over ``data`` vs replicated — the batch=1
-        fast path) at trace time."""
+        """The jitted batched forward ``fn(params, images)`` for one
+        (fusion, group-size) variant (built lazily — a policy that never
+        flips serves exactly one).  jit's own shape-keyed cache gives one
+        compiled program per bucket; the params are its arguments, not
+        constants baked into every bucket's program.  On a mesh the
+        variant key also carries the bucket's data-divisibility:
+        `build_sharded_fn` fixes the batch PartitionSpec (sharded over
+        ``data`` vs replicated — the batch=1 fast path) at trace time."""
         group = int(group) if fused else 1
         bucket = int(bucket) if bucket else self.buckets[0]
-        div = self.mp > 1 and bucket % self.dp == 0
-        key = (fused, group, div) if self.mp > 1 else (fused, group)
+        div = self.mesh is not None and bucket % self.dp == 0
+        key = (fused, group, div)
         fn = self._forwards.get(key)
         if fn is not None:
             return fn
         cfg = dataclasses.replace(self.cfg, fused=fused, fuse_group=group)
-        if self.mode == "int8":
-            p, obs = self.qparams, self.calibrator
-        else:
-            p, obs = self.params, None
-        # Patchify INSIDE the compiled program: the host-side drain then
-        # dispatches exactly one XLA call per micro-batch (the reshape
-        # fuses into the embed matmul instead of running eagerly per step).
-        if self.mp > 1:
-            # shard_map drain: weights arrive as local head / MLP-column
-            # shards, the executor psums at the two residual re-entries.
-            sched = vision_registry.make_schedule(cfg)
-            body = jax.jit(sched_lib.build_sharded_fn(
-                sched, p, self.mesh, batch=bucket, observer=obs,
-                preprocess=lambda im: vit.extract_patches(im, cfg.patch),
-                x_ndim=4))
+        obs = self.calibrator if self.mode == "int8" else None
 
-            def _fwd(images):
-                return body(p, images)
+        def patchify(images):
+            # Patchify INSIDE the compiled program: the host-side drain
+            # dispatches exactly one XLA call per micro-batch (the reshape
+            # fuses into the embed matmul instead of running eagerly).
+            return vit.extract_patches(images, cfg.patch)
+
+        if self.mesh is not None:
+            # shard_map drain: each device runs the kernels on its batch
+            # rows; on a model axis the weights arrive as local head /
+            # MLP-column shards and the executor psums at the two
+            # residual re-entries.
+            sched = vision_registry.make_schedule(cfg)
+            fn = jax.jit(sched_lib.build_sharded_fn(
+                sched, self.served_params, self.mesh, batch=bucket,
+                observer=obs, preprocess=patchify, x_ndim=4))
         else:
             model_fwd = vision_registry.forward_fn(cfg)
-            if self.mode == "int8":
-                def _fwd_inner(images):
-                    return model_fwd(
-                        p, vit.extract_patches(images, cfg.patch),
-                        cfg, observer=obs)
-            else:
-                def _fwd_inner(images):
-                    return model_fwd(
-                        p, vit.extract_patches(images, cfg.patch), cfg)
-            _fwd = jax.jit(_fwd_inner)
-        self._forwards[key] = _fwd
-        return _fwd
+            fn = jax.jit(lambda p, images: model_fwd(
+                p, patchify(images), cfg, observer=obs))
+        self._forwards[key] = fn
+        return fn
+
+    def _place(self, images: np.ndarray):
+        """Put a padded micro-batch on the device(s).  Buckets are rounded
+        to a multiple of the data-axis size, so on a mesh it lands
+        pre-sharded (batch on ``data``) before the single jitted call —
+        each device receives only its own shard straight from the host."""
+        if self.mesh is not None:
+            return shd.shard_vision_batch(images, self.mesh)
+        return jnp.asarray(images)
+
+    def compile_bucket(self, bucket: int):
+        """Compile the forward that serves ``bucket`` ahead of its first
+        request and return it as a `jax.stages.Compiled` (its
+        ``as_text()`` names the kernels the program runs).  jit keeps the
+        executable, so the bucket's first dispatch does not compile
+        again."""
+        bucket = int(bucket)
+        if bucket not in self.buckets:
+            raise ValueError(f"{bucket} is not one of this server's "
+                             f"buckets {self.buckets}")
+        images = self._place(np.zeros(
+            (bucket, self.cfg.image, self.cfg.image, 3), np.float32))
+        forward = self._forward_for(self._bucket_fused.get(bucket, True),
+                                    self._bucket_group.get(bucket, 1),
+                                    bucket)
+        return forward.lower(self.served_params, images).compile()
 
     # -- request plane ----------------------------------------------------
 
@@ -468,18 +494,11 @@ class VisionServer:
                            images.dtype)
             images = np.concatenate([images, pad])
             self.n_padded += bucket - len(requests)
-        if self.mesh is not None:
-            # Buckets are rounded to a multiple of the data-axis size, so
-            # the padded micro-batch lands pre-sharded (batch on ``data``)
-            # before the single jitted call — each device receives only
-            # its own shard straight from the host array.
-            batch_in = shd.shard_vision_batch(images, self.mesh)
-        else:
-            batch_in = jnp.asarray(images)
         forward = self._forward_for(self._bucket_fused.get(bucket, True),
                                     self._bucket_group.get(bucket, 1),
                                     bucket)
-        out = forward(batch_in)                # async: no block here
+        out = forward(self.served_params,      # async: no block here
+                      self._place(images))
         t = time.perf_counter()
         for req in requests:
             req.t_start = t
@@ -537,7 +556,7 @@ class VisionServer:
         group = group if fused else 1
         cfg = dataclasses.replace(self.cfg, fused=fused, fuse_group=group)
         sched = vision_registry.make_schedule(cfg)
-        params = self.qparams if self.mode == "int8" else self.params
+        params = self.served_params
         if self.mp > 1:
             # The per-phase profiler jits each phase on its own; pulling
             # the model-axis-sharded tree back to host profiles the
@@ -925,6 +944,7 @@ def main(argv=None):
     ap.add_argument("--json-out", default=None,
                     help="write stats as a BENCH_*.json-style record")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list_models:
         for name in vision_registry.list_models():

@@ -7,9 +7,11 @@ rel_bias head columns, and the w_msa concat rows with the H/K rescale
 folded in — the kernels derive their head extent from operand shapes and
 never see dead heads.  `expand_block_heads` is the inverse oracle: the
 DENSE schedule over zero-padded params must reproduce the pruned
-execution BIT-FOR-BIT (a zero head computes exact zeros; the concat adds
-exact 0.0 terms / int8 zero rows), so every parity assertion here is
-exact equality, not a tolerance.
+execution.  A zero head computes exact zeros and the concat adds exact
+0.0 terms / int8 zero rows, so int8 matches bit for bit.  Float matches
+to a few f32 ulps (`FLOAT_ORACLE_ATOL`): the concat projection contracts
+over K·Dh rows pruned and H·Dh rows dense, and XLA may sum the two
+contractions in a different order.
 """
 
 import dataclasses
@@ -35,6 +37,10 @@ from _hypothesis_compat import given, settings, strategies as st
 # drawn integer is a valid ragged mask.
 LAYERS, HEADS = 3, 4
 MASK_BITS = st.integers(min_value=0, max_value=2 ** (LAYERS * HEADS) - 1)
+
+# Float pruned-vs-dense-oracle bound on logits of magnitude ~1: about 16
+# f32 ulps of 1.0 (the registry variants measure up to 7e-7).
+FLOAT_ORACLE_ATOL = 2e-6
 
 
 def _mask_from_bits(bits):
@@ -231,8 +237,8 @@ PRUNED = [m for m in vision_registry.list_models() if m.endswith("_p")]
 @pytest.mark.parametrize("mode", ["float", "int8"])
 def test_pruned_variant_matches_dense_oracle(name, mode):
     """Each registered pruned variant reproduces the dense schedule over
-    its zero-expanded params exactly, float and int8 — the acceptance
-    oracle for the ragged masks shipping in the registry."""
+    its zero-expanded params — int8 exactly, float to a few ulps — the
+    acceptance oracle for the ragged masks shipping in the registry."""
     cfg = vision_registry.build_cfg(name)
     assert cfg.head_mask is not None
     dense_cfg = dataclasses.replace(cfg, head_mask=None)
@@ -255,9 +261,13 @@ def test_pruned_variant_matches_dense_oracle(name, mode):
         # so the requant chain quantizes to the same integers
         oracle = fwd(_expand_params(cfg, qparams), patches, dense_cfg,
                      observer=cal)
-    assert jnp.array_equal(pruned, oracle), (
-        name, mode,
-        np.abs(np.asarray(pruned) - np.asarray(oracle)).max())
+    if mode == "float":
+        np.testing.assert_allclose(pruned, oracle, rtol=0,
+                                   atol=FLOAT_ORACLE_ATOL, err_msg=name)
+    else:
+        assert jnp.array_equal(pruned, oracle), (
+            name, mode,
+            np.abs(np.asarray(pruned) - np.asarray(oracle)).max())
 
 
 @pytest.mark.parametrize("name", PRUNED)

@@ -511,3 +511,20 @@ def test_linear_recurrence_backends_agree():
         ops.linear_recurrence(a, b, backend="pallas"),
         ops.linear_recurrence(a, b, backend="xla"),
         rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("gpu", None)])
+def test_kernels_interpret_on_cpu_only(monkeypatch, platform, interpret):
+    """Kernels compile on the TPU and are interpreted on the CPU; on any
+    other platform the dispatch layer refuses instead of interpreting."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_ON_TPU", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interp()
+    else:
+        assert ops._interp() is interpret
+    monkeypatch.setattr(ops, "_ON_TPU", True)
+    assert ops._interp() is False

@@ -26,9 +26,9 @@ sys.path.insert(0, os.path.join(%r, "src"))
 import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import Mesh
 from repro.distributed.pipeline import pipeline_apply
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 key = jax.random.PRNGKey(0)
 n_stages, n_mb, d = 4, 8, 16
 ws = jax.random.normal(key, (n_stages, d, d)) * 0.3

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro import configs
 from repro.distributed import sharding as shd
@@ -21,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def production_abstract_mesh(multi_pod=False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return shd.abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 @pytest.mark.parametrize("arch", configs.list_archs())

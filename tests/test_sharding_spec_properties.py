@@ -25,6 +25,7 @@ so the matrix runs identically on the dev-1 and dev-8 CI legs.
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 
 from _hypothesis_compat import given, settings, strategies as st
 
@@ -71,7 +72,7 @@ def _qblock(heads: int, dh: int, hidden: int):
 
 
 def _mesh2(model: int):
-    return shd.abstract_mesh((2, model), ("data", "model"))
+    return AbstractMesh((2, model), ("data", "model"))
 
 
 def _spec(tree, model: int):
@@ -177,7 +178,7 @@ def test_w_msa_replicates_when_concat_dim_is_not_head_major():
 def test_no_model_axis_means_fully_replicated():
     """On the 1-D data mesh every leaf replicates (the GSPMD serving
     path) — the model-axis ladder must not leak in."""
-    mesh = shd.abstract_mesh((8,), ("data",))
+    mesh = AbstractMesh((8,), ("data",))
     specs = shd.vision_param_specs(
         {"layers": [_block(4, 2, 32)]}, mesh)
     for leaf in jax.tree_util.tree_leaves(

@@ -208,3 +208,42 @@ def test_pallas_and_xla_backends_agree(tiny_setup):
         logits[backend] = np.stack([r.logits for r in server.done])
     np.testing.assert_allclose(logits["pallas"], logits["xla"],
                                rtol=2e-4, atol=2e-4)
+
+
+def test_compile_bucket_ahead_of_traffic(tiny_setup):
+    """A bucket compiled ahead of its first request serves the same
+    logits as one compiled on first dispatch; unknown buckets raise."""
+    cfg, params, images = tiny_setup
+    warm = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(1, 4)))
+    compiled = warm.compile_bucket(4)
+    assert "dot" in compiled.as_text()
+    with pytest.raises(ValueError, match="buckets"):
+        warm.compile_bucket(3)
+    cold = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(1, 4)))
+    for server in (warm, cold):
+        server.submit_many(images[:4])
+        server.run()
+    np.testing.assert_array_equal(
+        np.stack([r.logits for r in warm.done]),
+        np.stack([r.logits for r in cold.done]))
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_fixed(monkeypatch, tmp_path, env_dir):
+    """The compile cache is $JAX_COMPILATION_CACHE_DIR when set (JAX reads
+    it; nothing else is configured), else .jax_cache/ at the checkout."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(compile_cache.CHECKOUT_ROOT / ".jax_cache")
+        assert (compile_cache.CHECKOUT_ROOT / "chip_smoke.py").exists()
+        assert compile_cache.enable_compile_cache() == want
+        assert calls == [("jax_compilation_cache_dir", want)]
+    else:
+        path = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+        assert compile_cache.enable_compile_cache() == path
+        assert calls == []

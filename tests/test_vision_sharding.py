@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.core import schedule as sched_lib
 from repro.core.quant import QTensor, ptq_tolerance
@@ -49,7 +50,7 @@ def test_vision_params_replicate_over_data(name):
     or quantization scale — may shard over the ``data`` axis, for any
     registered family's tree layout."""
     cfg = vision_registry.build_cfg(name)
-    mesh = shd.abstract_mesh((8,), ("data",))
+    mesh = AbstractMesh((8,), ("data",))
     for tree in (
             jax.eval_shape(lambda: vision_registry.init_params(
                 jax.random.PRNGKey(0), cfg)),
@@ -74,8 +75,8 @@ def test_vision_per_head_specs_use_fits_fallback():
         jax.random.PRNGKey(0), cfg))
     qshape = jax.eval_shape(lambda: vision_registry.quantize(
         vision_registry.init_params(jax.random.PRNGKey(0), cfg)))
-    mesh2 = shd.abstract_mesh((4, 2), ("data", "model"))
-    mesh16 = shd.abstract_mesh((2, 16), ("data", "model"))
+    mesh2 = AbstractMesh((4, 2), ("data", "model"))
+    mesh16 = AbstractMesh((2, 16), ("data", "model"))
     for tree in (pshape, qshape):
         spec2 = shd.vision_param_specs(tree, mesh2)
         spec16 = shd.vision_param_specs(tree, mesh16)
@@ -93,7 +94,7 @@ def test_vision_per_head_specs_use_fits_fallback():
 
 
 def test_vision_batch_spec_divisibility_fallback():
-    mesh = shd.abstract_mesh((4,), ("data",))
+    mesh = AbstractMesh((4,), ("data",))
     assert tuple(shd.vision_batch_spec(8, mesh)) == ("data",)
     assert tuple(shd.vision_batch_spec(5, mesh)) in ((None,), ())
 
