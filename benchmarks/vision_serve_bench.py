@@ -364,7 +364,6 @@ def bench_model(name: str, *, requests: int, batches, repeats: int,
                     serve_cfg=ServeConfig(mode=mode, buckets=(batch,)))
                 server.submit_many(images)
                 server.step()              # compile warm-up drain
-                server.restamp_queued()
                 server.run()
                 done = sorted(server.done, key=lambda r: r.rid)
                 logits[(mode, batch, variant)] = np.stack(
@@ -616,7 +615,6 @@ def head_sweep_model(name: str, *, requests: int, batches, repeats: int,
                 model_name=name)
             server.submit_many(images)
             server.step()                    # compile warm-up drain
-            server.restamp_queued()
             server.run()
             stats = _timed_ab_drains({"swept": server}, images,
                                      repeats)["swept"]

@@ -832,15 +832,37 @@ def run_schedule(sched: Schedule, params: Any, patches: jax.Array,
     ``shard``: `ShardCtx` when the replay body runs under `shard_map`
     with model-axis-sharded params (see `build_sharded_fn`); None for
     single-device and GSPMD data-parallel execution.
+
+    Each phase runs under its `phase_scopes` name, so the trace's device
+    operations say which phase they belong to.
     """
     obs = observer
     quantized = isinstance(params["patch_embed"], QTensor)
     x = patches
     inner: Optional[jax.Array] = None      # TNT pixel stream (B*N, m, c)
-    for ph in sched.phases:
-        x, inner = _apply_phase(sched, ph, params, x, inner, obs,
-                                quantized, shard)
+    for ph, scope in zip(sched.phases, phase_scopes(sched)):
+        with jax.named_scope(scope):
+            x, inner = _apply_phase(sched, ph, params, x, inner, obs,
+                                    quantized, shard)
     return x
+
+
+def phase_scopes(sched: Schedule) -> Tuple[str, ...]:
+    """The `jax.named_scope` each phase runs under in `run_schedule`:
+    ``embed`` and ``head``, and every other phase its kind numbered in
+    schedule order (``layer0`` ..., or ``msa0`` / ``mlp0`` ...,
+    ``merge0``).  A device operation's metadata then names the phase it
+    computes, whatever kernel implements it."""
+    seen: Dict[str, int] = {}
+    out = []
+    for ph in sched.phases:
+        if ph.kind in ("embed", "head"):
+            out.append(ph.kind)
+        else:
+            k = seen.get(ph.kind, 0)
+            seen[ph.kind] = k + 1
+            out.append(f"{ph.kind}{k}")
+    return tuple(out)
 
 
 def profile_schedule(sched: Schedule, params: Any, patches: jax.Array,

@@ -52,6 +52,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.launch.vision_serve import InFlight, VisionRequest, VisionServer
 
 
@@ -330,20 +331,32 @@ class AdmissionController:
         """One scheduling iteration: refill the dispatch ring (assembly
         overlaps the executing batch — jax dispatch is async), then
         block on the OLDEST in-flight micro-batch.  Returns the number
-        of requests completed."""
-        now = time.perf_counter() if now is None else now
-        while len(self.ring) < self.max_inflight:
-            plan = self._assemble(now)
-            if plan is None:
-                break
-            server, group, bucket, _ = plan
-            self.ring.append((server, server.dispatch(group, bucket)))
-        if not self.ring:
-            return 0
-        server, inflight = self.ring.pop(0)
-        served = server.complete(inflight)
-        self.completed.extend(inflight.requests)
-        return served
+        of requests completed.  Records ``admission.step`` with each
+        ``admission.assemble`` inside it (`core.spans`)."""
+        on = spans.on()
+        if on:
+            top = spans.begin("admission.step")
+        try:
+            now = time.perf_counter() if now is None else now
+            while len(self.ring) < self.max_inflight:
+                if on:
+                    part = spans.begin("admission.assemble")
+                plan = self._assemble(now)
+                if on:
+                    spans.end(part)
+                if plan is None:
+                    break
+                server, group, bucket, _ = plan
+                self.ring.append((server, server.dispatch(group, bucket)))
+            if not self.ring:
+                return 0
+            server, inflight = self.ring.pop(0)
+            served = server.complete(inflight)
+            self.completed.extend(inflight.requests)
+            return served
+        finally:
+            if on:
+                spans.end(top)
 
     def drain(self) -> int:
         """Flush every queued and in-flight request (stream shutdown)."""
@@ -374,7 +387,7 @@ def stream_summary(reqs: Sequence[VisionRequest],
                    wall_s: float) -> Dict[str, object]:
     """The shared open-stream stats row: sustained throughput over the
     whole stream plus tail latency with queue-delay / service-time split
-    (no `restamp_queued` needed — the spans are stamped separately)."""
+    (each request's queue delay and service time are stamped apart)."""
     n = len(reqs)
     if n == 0:
         zeros = {k: 0.0 for k in

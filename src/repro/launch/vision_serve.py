@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import json
 import os
 import time
@@ -73,6 +74,7 @@ import numpy as np
 
 from repro.core import hue as hue_lib
 from repro.core import schedule as sched_lib
+from repro.core import spans
 from repro.core.quant import Calibrator
 from repro.core.schedule import FusionPolicy
 from repro.distributed import sharding as shd
@@ -149,6 +151,10 @@ class ServeConfig:
         object.__setattr__(self, "buckets", buckets)
 
 
+# Ids of dispatched micro-batches, unique across the process's servers.
+_BATCH_IDS = itertools.count()
+
+
 # Sentinel distinguishing "kwarg not passed" from an explicit None on the
 # deprecated VisionServer keyword surface (None is a meaningful value for
 # most of them).
@@ -161,11 +167,12 @@ class VisionRequest:
     Timing is three stamps — ``t_submit`` (queued), ``t_start`` (its
     micro-batch was dispatched) and ``t_done`` (logits materialized) — so
     queue delay and service time are reported SEPARATELY
-    (`queue_delay_s` / `service_s`).  On the open-stream admission path
-    that makes `restamp_queued` unnecessary: a warm-up drain inflates
-    only the warm-up requests' service time, never a later request's
-    queue delay.  ``latency_s`` (the full submit→done span) is kept for
+    (`queue_delay_s` / `service_s`): a warm-up drain inflates only the
+    warm-up requests' service time, never a later request's queue
+    delay.  ``latency_s`` (the full submit→done span) is kept for
     drain-mode compatibility — every existing stats consumer reads it.
+    ``batch`` is the id of the micro-batch that carried the request, the
+    argument of its ``serve.*`` spans (`core.spans`).
 
     ``sla_ms`` is the request's latency budget (None = no deadline);
     the admission layer's SLA-aware bucket selector
@@ -180,6 +187,7 @@ class VisionRequest:
         self.t_submit = time.perf_counter()
         self.t_start: Optional[float] = None
         self.t_done: Optional[float] = None
+        self.batch: Optional[int] = None
         self.pred: Optional[int] = None
         self.logits: Optional[np.ndarray] = None
 
@@ -220,17 +228,19 @@ class InFlight:
     (jax dispatches without blocking), so the caller can assemble and
     dispatch the next micro-batch while this one executes — the
     admission layer's dispatch ring.  `VisionServer.complete` blocks on
-    ``out`` and stamps the requests.
+    ``out`` and stamps the requests.  ``batch`` is the dispatch's id,
+    unique in the process.
     """
 
-    __slots__ = ("requests", "bucket", "out", "t_dispatch")
+    __slots__ = ("requests", "bucket", "out", "t_dispatch", "batch")
 
     def __init__(self, requests: List[VisionRequest], bucket: int, out,
-                 t_dispatch: float):
+                 t_dispatch: float, batch: int):
         self.requests = requests
         self.bucket = bucket
         self.out = out
         self.t_dispatch = t_dispatch
+        self.batch = batch
 
 
 class VisionServer:
@@ -488,36 +498,68 @@ class VisionServer:
         if len(requests) > bucket:
             raise ValueError(
                 f"{len(requests)} requests cannot ride a {bucket}-bucket")
-        images = np.stack([r.image for r in requests])
-        if bucket > len(requests):             # pad up to the bucket size
-            pad = np.zeros((bucket - len(requests),) + images.shape[1:],
-                           images.dtype)
-            images = np.concatenate([images, pad])
-            self.n_padded += bucket - len(requests)
-        forward = self._forward_for(self._bucket_fused.get(bucket, True),
-                                    self._bucket_group.get(bucket, 1),
-                                    bucket)
-        out = forward(self.served_params,      # async: no block here
-                      self._place(images))
-        t = time.perf_counter()
-        for req in requests:
-            req.t_start = t
-        self.n_batches += 1
-        return InFlight(requests, bucket, out, t)
+        batch = next(_BATCH_IDS)
+        on = spans.on()
+        if on:
+            top = spans.begin("serve.dispatch", batch)
+            part = spans.begin("serve.stack", batch)
+        try:
+            images = np.stack([r.image for r in requests])
+            if bucket > len(requests):         # pad up to the bucket size
+                pad = np.zeros((bucket - len(requests),) + images.shape[1:],
+                               images.dtype)
+                images = np.concatenate([images, pad])
+                self.n_padded += bucket - len(requests)
+            if on:
+                part = spans.switch(part, "serve.place", batch)
+            placed = self._place(images)
+            if on:
+                part = spans.switch(part, "serve.launch", batch)
+            forward = self._forward_for(self._bucket_fused.get(bucket, True),
+                                        self._bucket_group.get(bucket, 1),
+                                        bucket)
+            out = forward(self.served_params, placed)  # async: no block
+            if on:
+                spans.end(part)
+            t = time.perf_counter()
+            for req in requests:
+                req.t_start = t
+                req.batch = batch
+            self.n_batches += 1
+            return InFlight(requests, bucket, out, t, batch)
+        finally:
+            if on:
+                spans.end(top)
 
     def complete(self, inflight: Optional[InFlight]) -> int:
         """Block until an in-flight micro-batch's logits materialize and
         stamp its requests done; returns the number of requests served."""
         if inflight is None:
             return 0
-        logits = np.asarray(jax.block_until_ready(inflight.out))
-        t = time.perf_counter()
-        for i, req in enumerate(inflight.requests):
-            req.t_done = t
-            req.logits = logits[i]
-            req.pred = int(np.argmax(logits[i]))
-        self.done.extend(inflight.requests)
-        return len(inflight.requests)
+        batch = inflight.batch
+        on = spans.on()
+        if on:
+            top = spans.begin("serve.complete", batch)
+            part = spans.begin("serve.wait", batch)
+        try:
+            out = jax.block_until_ready(inflight.out)
+            if on:
+                part = spans.switch(part, "serve.readback", batch)
+            logits = np.asarray(out)
+            t = time.perf_counter()
+            if on:
+                part = spans.switch(part, "serve.stamp", batch)
+            for i, req in enumerate(inflight.requests):
+                req.t_done = t
+                req.logits = logits[i]
+                req.pred = int(np.argmax(logits[i]))
+            self.done.extend(inflight.requests)
+            if on:
+                spans.end(part)
+            return len(inflight.requests)
+        finally:
+            if on:
+                spans.end(top)
 
     def step(self) -> int:
         """Drain one micro-batch; returns the number of requests served.
@@ -577,19 +619,6 @@ class VisionServer:
                        "group_size": group, "devices": self.n_devices,
                        "mesh_shape": self.mesh_shape})
         return report
-
-    def restamp_queued(self) -> None:
-        """Reset queued requests' submit clocks (e.g. after a warm-up drain,
-        so reported latencies are steady-state, not compile time).
-
-        DRAIN-MODE ONLY: the open-stream admission path never needs this
-        — queue delay and service time are stamped separately
-        (`VisionRequest.queue_delay_s` / `service_s`), so a warm-up
-        drain's compile time lands in the warm-up requests' service
-        span instead of polluting later requests' reported latency."""
-        t = time.perf_counter()
-        for r in self.queue:
-            r.t_submit = t
 
     def run(self) -> Dict[str, float]:
         """Drain the whole queue and return this run's serving statistics."""

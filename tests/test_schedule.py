@@ -434,3 +434,44 @@ def test_registry_builds_and_schedules(name):
     # backend override lands in both the config and the compiled schedule
     bcfg = vision_registry.build_cfg(name, backend="pallas")
     assert vision_registry.make_schedule(bcfg).backend == "pallas"
+
+
+# ---------------------------------------------------------------------------
+# Named scopes per phase
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_phases_run_under_named_scopes(monkeypatch, fused):
+    """The lowered program names every phase's scope in its op metadata
+    (``embed``, ``layer<i>`` or ``msa<i>`` / ``mlp<i>``, ``head``), and
+    the scopes change no operation: without them the program is the
+    same."""
+    import contextlib
+
+    cfg = dataclasses.replace(vision_registry.build_cfg(
+        "vit_edge", backend="xla"), fused=fused)
+    sched = vision_registry.make_schedule(cfg)
+    scopes = sched_lib.phase_scopes(sched)
+    layers = [f"layer{i}" for i in range(cfg.layers)] if fused else \
+        [f"{k}{i}" for i in range(cfg.layers) for k in ("msa", "mlp")]
+    assert sorted(scopes) == sorted(["embed", "head"] + layers)
+    assert scopes[0] == "embed" and scopes[-1] == "head"
+    params = vit.init_params(jax.random.PRNGKey(0), cfg)
+    patches = jax.ShapeDtypeStruct(
+        (2, (cfg.image // cfg.patch) ** 2, cfg.patch * cfg.patch * 3),
+        jnp.float32)
+
+    def lower():
+        return jax.jit(lambda p, x: vit.forward(p, x, cfg)).lower(
+            params, patches)
+
+    scoped = lower()
+    text = scoped.as_text(debug_info=True)
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = lower()
+    assert "/layer0/" not in plain.as_text(debug_info=True)
+    assert scoped.as_text() == plain.as_text()
