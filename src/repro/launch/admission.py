@@ -101,7 +101,9 @@ def measure_bucket_latencies(server: VisionServer, *,
     cfg = server.cfg
     shape = (cfg.image, cfg.image, 3)
     done0 = len(server.done)
-    batches0, padded0 = server.n_batches, server.n_padded
+    counters = ("n_batches", "n_padded", "n_stack_reused",
+                "n_stack_allocated")
+    counters0 = [getattr(server, c) for c in counters]
     out: Dict[int, float] = {}
     for b in server.buckets:
         def probe():
@@ -113,7 +115,8 @@ def measure_bucket_latencies(server: VisionServer, *,
         probe()                                  # compile warm-up
         out[b] = min(probe() for _ in range(max(repeats, 1)))
     del server.done[done0:]
-    server.n_batches, server.n_padded = batches0, padded0
+    for c, v in zip(counters, counters0):
+        setattr(server, c, v)
     return out
 
 

@@ -229,18 +229,25 @@ class InFlight:
     dispatch the next micro-batch while this one executes — the
     admission layer's dispatch ring.  `VisionServer.complete` blocks on
     ``out`` and stamps the requests.  ``batch`` is the dispatch's id,
-    unique in the process.
+    unique in the process.  ``images`` is the server's host buffer the
+    micro-batch was stacked in, ``stack_key`` the pool it belongs to: it
+    stays out of the server's pool until `complete` hands it back (an
+    `InFlight` never completed keeps it).
     """
 
-    __slots__ = ("requests", "bucket", "out", "t_dispatch", "batch")
+    __slots__ = ("requests", "bucket", "out", "t_dispatch", "batch",
+                 "images", "stack_key")
 
     def __init__(self, requests: List[VisionRequest], bucket: int, out,
-                 t_dispatch: float, batch: int):
+                 t_dispatch: float, batch: int, images: np.ndarray,
+                 stack_key: Tuple):
         self.requests = requests
         self.bucket = bucket
         self.out = out
         self.t_dispatch = t_dispatch
         self.batch = batch
+        self.images = images
+        self.stack_key = stack_key
 
 
 class VisionServer:
@@ -371,6 +378,13 @@ class VisionServer:
         self.done: List[VisionRequest] = []
         self.n_batches = 0
         self.n_padded = 0
+        # Host buffers that micro-batches are stacked in, free for reuse,
+        # keyed by (bucket, image shape, image strides, dtype);
+        # ``n_stack_reused`` counts the dispatches that found one,
+        # ``n_stack_allocated`` those that made one (`dispatch`).
+        self._stack_free: Dict[Tuple, List[np.ndarray]] = {}
+        self.n_stack_reused = 0
+        self.n_stack_allocated = 0
         self._rid = 0
         self._forwards: Dict[Tuple, callable] = {}
 
@@ -485,6 +499,17 @@ class VisionServer:
         fits — the SLA-aware scheduler overrides it with its measured
         pick.  Each request's ``t_start`` is stamped here, so queue
         delay and service time split at the dispatch boundary.
+
+        The images are stacked into a host buffer the server owns, taken
+        from a free list for the (bucket, image shape, image strides,
+        dtype) or, when none is free, made by `np.stack` itself, so that
+        it is laid out as the images are (an array read back from a
+        device need not be C-ordered; copying it into another order
+        costs several times a straight copy).  Padding rows are zeroed.
+        The buffer rides on the returned `InFlight` and is reused only
+        after `complete` of that `InFlight`, when the forward's output
+        is ready and so its input transfer is over, whatever the backend
+        does with host memory.
         """
         if requests is None:
             if not self.queue:
@@ -504,12 +529,22 @@ class VisionServer:
             top = spans.begin("serve.dispatch", batch)
             part = spans.begin("serve.stack", batch)
         try:
-            images = np.stack([r.image for r in requests])
-            if bucket > len(requests):         # pad up to the bucket size
-                pad = np.zeros((bucket - len(requests),) + images.shape[1:],
-                               images.dtype)
-                images = np.concatenate([images, pad])
-                self.n_padded += bucket - len(requests)
+            rows = [r.image for r in requests]
+            k = len(rows)
+            key = (bucket, rows[0].shape, rows[0].strides,
+                   np.result_type(*{im.dtype for im in rows}))
+            free = self._stack_free.get(key)
+            if free:
+                images = free.pop()
+                np.stack(rows, out=images[:k])
+                self.n_stack_reused += 1
+            else:
+                images = np.stack(
+                    rows + [np.zeros_like(rows[0])] * (bucket - k))
+                self.n_stack_allocated += 1
+            if bucket > k:    # pad with zeros, over an earlier batch's rows
+                images[k:] = 0
+                self.n_padded += bucket - k
             if on:
                 part = spans.switch(part, "serve.place", batch)
             placed = self._place(images)
@@ -526,14 +561,17 @@ class VisionServer:
                 req.t_start = t
                 req.batch = batch
             self.n_batches += 1
-            return InFlight(requests, bucket, out, t, batch)
+            return InFlight(requests, bucket, out, t, batch, images, key)
         finally:
             if on:
                 spans.end(top)
 
     def complete(self, inflight: Optional[InFlight]) -> int:
         """Block until an in-flight micro-batch's logits materialize and
-        stamp its requests done; returns the number of requests served."""
+        stamp its requests done; returns the number of requests served.
+        Once the logits are ready the micro-batch's host buffer goes back
+        to the server's free list, once, for the next `dispatch` of its
+        (bucket, image shape, image strides, dtype)."""
         if inflight is None:
             return 0
         batch = inflight.batch
@@ -549,6 +587,10 @@ class VisionServer:
             t = time.perf_counter()
             if on:
                 part = spans.switch(part, "serve.stamp", batch)
+            images, inflight.images = inflight.images, None
+            if images is not None:
+                self._stack_free.setdefault(
+                    inflight.stack_key, []).append(images)
             for i, req in enumerate(inflight.requests):
                 req.t_done = t
                 req.logits = logits[i]

@@ -3,12 +3,14 @@ latency bookkeeping, float-vs-int8 PTQ agreement, and a round-trip through
 every model in the vision registry (one pipeline, many control programs)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.quant import ptq_tolerance
-from repro.launch.vision_serve import (ServeConfig, VisionServer,
-                                       build_edge_vit, calibrate)
+from repro.launch.vision_serve import (ServeConfig, VisionRequest,
+                                       VisionServer, build_edge_vit,
+                                       calibrate)
 from repro.models import vision_registry, vit
 
 
@@ -247,3 +249,98 @@ def test_compile_cache_dir_is_fixed(monkeypatch, tmp_path, env_dir):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
         assert compile_cache.enable_compile_cache() == path
         assert calls == []
+
+
+def _requests(images):
+    return [VisionRequest(i, im) for i, im in enumerate(images)]
+
+
+@pytest.mark.parametrize("counts", [(4, 3, 1, 2), (2, 4, 1, 4, 3)])
+def test_pooled_stack_matches_a_fresh_stack(tiny_setup, counts):
+    """Consecutive dispatches of different request counts into one bucket
+    share one host buffer, and each one's logits equal, bit for bit, the
+    same program's on a fresh `np.stack` padded with zeros; a padded batch
+    after a fuller one finds its padding rows zeroed."""
+    cfg, params, images = tiny_setup
+    server = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(4,)))
+    forward = server.compile_bucket(4)
+    buffers = set()
+    start = 0
+    for k in counts:
+        batch = images[start:start + k]
+        start = (start + k) % (len(images) - 4)
+        inflight = server.dispatch(_requests(batch))
+        buffers.add(id(inflight.images))
+        assert not inflight.images[k:].any()
+        server.complete(inflight)
+        fresh = np.concatenate([np.stack(list(batch)),
+                                np.zeros((4 - k,) + batch.shape[1:],
+                                         batch.dtype)])
+        want = np.asarray(forward(server.served_params, jnp.asarray(fresh)))
+        np.testing.assert_array_equal(
+            np.stack([r.logits for r in inflight.requests]), want[:k])
+    assert len(buffers) == 1
+    assert server.n_stack_allocated == 1
+    assert server.n_stack_reused == len(counts) - 1
+    assert server.n_padded == sum(4 - k for k in counts)
+
+
+def test_stack_buffer_returns_only_on_complete(tiny_setup):
+    """Buffers of micro-batches in flight are never shared; completing
+    one returns its buffer, once, and the next dispatch reuses it."""
+    cfg, params, images = tiny_setup
+    server = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(2,)))
+    server.submit_many(np.concatenate([images, images])[:14])
+    ring = [server.dispatch() for _ in range(3)]
+    assert len({id(f.images) for f in ring}) == 3
+    assert (server.n_stack_allocated, server.n_stack_reused) == (3, 0)
+    buffers = [f.images for f in ring]
+    for inflight in ring:
+        server.complete(inflight)
+        assert inflight.images is None
+    server.complete(ring[0])        # a second complete hands back nothing
+    nxt = server.dispatch()
+    assert any(nxt.images is b for b in buffers)
+    assert (server.n_stack_allocated, server.n_stack_reused) == (3, 1)
+    ring = [nxt] + [server.dispatch() for _ in range(3)]
+    assert len({id(f.images) for f in ring}) == 4
+    assert (server.n_stack_allocated, server.n_stack_reused) == (4, 3)
+    for inflight in ring:
+        server.complete(inflight)
+    assert not server.queue
+
+
+@pytest.mark.parametrize("odd", ["dtype", "shape", "layout"])
+def test_stack_buffer_per_image_shape_and_dtype(tiny_setup, odd):
+    """Images of another dtype, shape or memory layout in the same bucket
+    are stacked in a buffer of their own, laid out as they are, so the
+    bucket's float32 C-ordered buffer stays free."""
+    cfg, params, images = tiny_setup
+    server = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(2,)))
+    first = server.dispatch(_requests(images[:2]))
+    buf = first.images
+    server.complete(first)
+    if odd == "dtype":
+        other = server.dispatch(_requests(images[2:4].astype(np.float64)))
+        assert other.images is not buf
+        assert other.images.dtype == np.float64
+        server.complete(other)
+    elif odd == "layout":        # channels outermost in memory
+        planar = np.ascontiguousarray(images[2:4].transpose(0, 3, 1, 2))
+        other = server.dispatch(_requests(planar.transpose(0, 2, 3, 1)))
+        assert other.images is not buf
+        assert other.images[0].strides == planar[0].transpose(1, 2, 0).strides
+        server.complete(other)
+        want = server.compile_bucket(2)(server.served_params,
+                                        jnp.asarray(images[2:4]))
+        np.testing.assert_array_equal(
+            np.stack([r.logits for r in other.requests]), np.asarray(want))
+    else:
+        with pytest.raises((TypeError, ValueError)):   # the model refuses it
+            server.dispatch(_requests(
+                np.zeros((2, cfg.image + 8, cfg.image, 3), np.float32)))
+    assert (server.n_stack_allocated, server.n_stack_reused) == (2, 0)
+    again = server.dispatch(_requests(images[4:6]))
+    assert again.images is buf
+    assert (server.n_stack_allocated, server.n_stack_reused) == (2, 1)
+    server.complete(again)
