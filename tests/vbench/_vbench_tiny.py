@@ -21,13 +21,16 @@ GEOMETRY = {"image": 32, "patch": 8, "dim": 96, "heads": 4, "layers": 4,
             "mlp_hidden": 384, "n_classes": 10, "ln_eps": 1e-05}
 
 
-def config(mode: str, limit: float, chips=(1,)) -> dict:
+def config(mode: str, limit: float, chips=(1,), registry="vit_edge",
+           family="vit", geometry=GEOMETRY) -> dict:
+    """A configuration of the program's reduced ``registry`` model, whose
+    family modules are ``vbench.{reference,work}.<family>``."""
     return {
-        "name": f"tiny_{mode}", "registry": "vit_edge", "full": False,
-        "family": "vit", "mode": mode, "backend": "xla",
+        "name": f"tiny_{mode}", "registry": registry, "full": False,
+        "family": family, "mode": mode, "backend": "xla",
         "buckets": [1, 2, 4], "chips": list(chips),
         "arithmetic": "int8" if mode == "int8" else "bf16",
-        "calib_images": 4, "geometry": GEOMETRY,
+        "calib_images": 4, "geometry": geometry,
         "kernels": {"vita_layer": "vita_layer"},
         "correct": {"reference": "f32" if mode == "int8" else "f32_bf16dot",
                     "control": "int4" if mode == "int8" else "int8",

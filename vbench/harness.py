@@ -122,13 +122,20 @@ def make_bank(seed: int, n: int, image: int) -> np.ndarray:
                                         (n, image, image, 3)))
 
 
-def check_geometry(cfg, geometry: Dict[str, Any]) -> None:
-    """The program's model must have the configuration's sizes."""
-    have = {"image": cfg.image, "patch": cfg.patch, "dim": cfg.dim,
-            "heads": cfg.heads, "layers": cfg.layers,
-            "mlp_hidden": cfg.mlp_hidden, "n_classes": cfg.n_classes}
-    for k, v in have.items():
-        if int(geometry[k]) != int(v):
+def _sizes(v):
+    """A size as compared: a sequence as a tuple of its sizes."""
+    return tuple(_sizes(x) for x in v) if isinstance(v, (list, tuple)) \
+        else v
+
+
+def check_geometry(cfg, geometry: Dict[str, Any], family) -> None:
+    """The program's model ``cfg`` must have the configuration's sizes:
+    each that the family's ``program_geometry`` reads from it."""
+    for k, v in family.program_geometry(cfg).items():
+        if k not in geometry:
+            raise spec_lib.SpecError(
+                f"configuration gives no {k}; the program's model has {v}")
+        if _sizes(geometry[k]) != _sizes(v):
             raise spec_lib.SpecError(
                 f"configuration says {k}={geometry[k]}, the program's "
                 f"model has {v}")
@@ -146,7 +153,8 @@ def build_server(cell: spec_lib.Cell, params, bank: np.ndarray, seed: int):
                      calib_images=int(c.get("calib_images", 8)))
     calib = bank[:sc.calib_images] if c["mode"] == "int8" else None
     server = make_server(c["registry"], sc, params=params, calib_bank=calib)
-    check_geometry(server.cfg, c["geometry"])
+    check_geometry(server.cfg, c["geometry"],
+                   spec_lib.family_module("reference", c))
     return server
 
 

@@ -31,19 +31,19 @@ def vita_layer_roofline(run):
     """The least time the chip could take for the encoder-layer kernel
     calls of the traced window, max(operations / peak, bytes / HBM
     bandwidth) per call and chip, over their summed device time in the
-    trace, in percent.  The peak is the highest that the kernel's
-    operands allow."""
+    trace, in percent.  The calls of one forward are the family's
+    ``layer_calls``; the peak is the highest that the kernel's operands
+    allow."""
     if run.trace is None or not run.trace.kernel_s.get(KERNEL):
         return None
     weight_bytes = 1 if run.arithmetic == "int8" else 4
     peak = run.peaks[run.arithmetic]
-    layers = int(run.geometry["layers"])
     least = 0.0
     for _, _, bucket, _ in run.traced_dispatches:
-        ops, nbytes = run.work.layer_call(run.geometry, bucket // run.chips,
-                                          weight_bytes)
-        least += max(ops / peak, nbytes / run.peaks["hbm_bytes_s"]) \
-            * layers * run.chips
+        for ops, nbytes in run.work.layer_calls(
+                run.geometry, bucket // run.chips, weight_bytes):
+            least += max(ops / peak, nbytes / run.peaks["hbm_bytes_s"]) \
+                * run.chips
     return 100.0 * least / run.trace.kernel_s[KERNEL]
 
 

@@ -12,6 +12,9 @@ jitted call, in the pytree layout the served program reads:
 per-head ``wq/wk/wv`` stacks of shape (H, D, Dh), the attention output
 projection ``w_msa`` (H*Dh, D) with head-major rows, and the MLP.
 
+``program_geometry`` reads the program's model config back in the
+configuration's geometry keys.
+
 ``forward`` is the reference.  ``precision`` picks its arithmetic:
 
 * ``"f32"``: float32 throughout, every matmul at ``highest`` precision;
@@ -45,6 +48,15 @@ QUANT_MAX = {"int8": 127.0, "int4": 7.0}
 def _geom(g: Mapping[str, Any]):
     return (int(g["image"]), int(g["patch"]), int(g["dim"]), int(g["heads"]),
             int(g["layers"]), int(g["mlp_hidden"]), int(g["n_classes"]))
+
+
+def program_geometry(cfg) -> Dict[str, Any]:
+    """The sizes of the program's model ``cfg`` under the keys of a
+    configuration's ``geometry``: what the harness holds the
+    configuration to."""
+    return {"image": cfg.image, "patch": cfg.patch, "dim": cfg.dim,
+            "heads": cfg.heads, "layers": cfg.layers,
+            "mlp_hidden": cfg.mlp_hidden, "n_classes": cfg.n_classes}
 
 
 def tokens(g: Mapping[str, Any]) -> int:

@@ -6,6 +6,38 @@ that the ``configs`` entry names, its traffic mix is
 ``vbench/metrics/<metric>.py``, a module with one function
 ``read(run) -> float | None``.  Adding a cell, a configuration, a mix or
 a metric therefore adds files and entries and edits none.
+
+What depends on the model family lives in two modules of the family,
+found by the configuration's ``family`` (``family_module``), so that a
+family joins by files alone:
+
+``vbench/reference/<family>.py``, the plain reference, which imports
+nothing of the program:
+
+* ``init_params(key, geometry)``: the weights from a PRNG key, made on
+  the device in one jitted call, in the pytree layout the program reads;
+* ``forward(params, images, geometry, *, precision, act_scales)``:
+  logits (B, n_classes) as a NumPy array, computed in ``precision``
+  (the configuration's ``correct.reference`` or ``correct.control``);
+* ``QUANT_MAX``: the fake-quantized precisions it computes, each with
+  its largest quantized magnitude, and ``calibrate(params, images,
+  geometry, precision)``, their activation scales (called only for a
+  precision in ``QUANT_MAX``);
+* ``program_geometry(cfg)``: the sizes of the program's model config
+  ``cfg`` under the keys of the configuration's ``geometry``; the
+  harness compares each with the configuration's, sequences as
+  sequences.
+
+``vbench/work/<family>.py``, operations and bytes from the shapes:
+
+* ``model_ops_per_image(geometry)``: the operations of one image's
+  forward;
+* ``layer_calls(geometry, batch, weight_bytes)``: (operations, least
+  bytes) of each encoder-layer kernel call of one forward on ``batch``
+  images, one item per call.
+
+The harness itself reads only ``image`` and ``n_classes`` of a
+``geometry``.
 """
 
 from __future__ import annotations

@@ -64,3 +64,9 @@ def layer_call(g: Mapping[str, Any], batch: int, weight_bytes: int):
     ops = batch * layer_ops_per_image(g)
     act = 2.0 * batch * n * d * F32
     return ops, layer_weight_bytes(g, weight_bytes) + act
+
+
+def layer_calls(g: Mapping[str, Any], batch: int, weight_bytes: int):
+    """(operations, least bytes) of each encoder-layer kernel call of one
+    forward on ``batch`` images: ``layers`` identical calls."""
+    return [layer_call(g, batch, weight_bytes)] * int(g["layers"])
