@@ -494,26 +494,47 @@ def _per_head_msa(bp: Any, z: jax.Array, obs, site: str,
                                             ).astype(z.dtype)
 
 
+def _window_fold(ph: Phase, x: jax.Array, rel_bias: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A windowed phase's input (B, T, C) shifted by ``ph.shift`` and
+    folded into (B*nW, n, C) windows, with the relative-position bias
+    gathered from its table (..., (2w-1)^2, H) into (..., H, n, n) and
+    the (nW, n, n) region mask.  Runs under the ``fold`` scope, so the
+    trace tells this glue from the kernel it feeds."""
+    b, _, c = x.shape
+    gh, gw = ph.grid
+    with jax.named_scope("fold"):
+        xs = x.reshape(b, gh, gw, c)
+        if ph.shift:
+            xs = jnp.roll(xs, (-ph.shift, -ph.shift), axis=(1, 2))
+        xw = window_partition(xs, ph.window)
+        idx = jnp.asarray(rel_pos_index(ph.window))
+        bias = jnp.moveaxis(rel_bias[..., idx, :], -1, -3)
+        mask = jnp.asarray(shifted_window_mask(gh, gw, ph.window,
+                                               ph.shift))
+    return xw, bias, mask
+
+
+def _window_unfold(ph: Phase, yw: jax.Array, b: int) -> jax.Array:
+    """Inverse of `_window_fold`'s fold and shift, to (B, T, C'), under
+    the ``unfold`` scope."""
+    gh, gw = ph.grid
+    with jax.named_scope("unfold"):
+        y = window_reverse(yw, ph.window, gh, gw)
+        if ph.shift:
+            y = jnp.roll(y, (ph.shift, ph.shift), axis=(1, 2))
+        return y.reshape(b, gh * gw, y.shape[-1])
+
+
 def _msa_phase(ph: Phase, bp: Any, x: jax.Array, obs, quantized: bool,
                backend: Optional[str],
                shard: Optional[ShardCtx] = None) -> jax.Array:
-    b, t, c = x.shape
     z = ops.layer_norm(x, bp["ln1_w"], bp["ln1_b"])
     if ph.window:
-        gh, gw = ph.grid
-        zs = z.reshape(b, gh, gw, c)
-        if ph.shift:
-            zs = jnp.roll(zs, (-ph.shift, -ph.shift), axis=(1, 2))
-        zw = window_partition(zs, ph.window)            # (B*nW, n, C)
-        idx = jnp.asarray(rel_pos_index(ph.window))
-        bias = bp["rel_bias"][idx].transpose(2, 0, 1)   # (H, n, n)
-        mask = jnp.asarray(shifted_window_mask(gh, gw, ph.window, ph.shift))
+        zw, bias, mask = _window_fold(ph, z, bp["rel_bias"])
         sa = _per_head_msa(bp, zw, obs, ph.site, quantized,
                            backend, bias, mask)
-        sa = window_reverse(sa, ph.window, gh, gw)
-        if ph.shift:
-            sa = jnp.roll(sa, (ph.shift, ph.shift), axis=(1, 2))
-        sa = sa.reshape(b, t, sa.shape[-1])     # local width when sharded
+        sa = _window_unfold(ph, sa, x.shape[0])   # local width if sharded
     else:
         sa = _per_head_msa(bp, z, obs, ph.site, quantized,
                            backend, None, None)
@@ -596,27 +617,16 @@ def _layer_phase(ph: Phase, bp: Any, x: jax.Array, obs, quantized: bool,
     if quantized and (obs is None or obs.frozen is None):
         x = _msa_phase(ph, bp, x, obs, quantized, backend, shard)
         return _mlp_phase(ph, bp, x, obs, quantized, backend, shard)
-    b, t, c = x.shape
     if not ph.window:
         return _fused_layer_call(ph, bp, x, obs, quantized, backend,
                                  None, None, shard)
     # W-MSA: LN / concat / residual / MLP are all per-token maps, so the
     # WHOLE fused layer commutes with the window permutation — fold the
     # windows into the batch axis, run the fused chain, unfold.
-    gh, gw = ph.grid
-    xs = x.reshape(b, gh, gw, c)
-    if ph.shift:
-        xs = jnp.roll(xs, (-ph.shift, -ph.shift), axis=(1, 2))
-    xw = window_partition(xs, ph.window)                # (B*nW, n, C)
-    idx = jnp.asarray(rel_pos_index(ph.window))
-    bias = bp["rel_bias"][idx].transpose(2, 0, 1)       # (H, n, n) local
-    mask = jnp.asarray(shifted_window_mask(gh, gw, ph.window, ph.shift))
+    xw, bias, mask = _window_fold(ph, x, bp["rel_bias"])
     yw = _fused_layer_call(ph, bp, xw, obs, quantized, backend, bias, mask,
                            shard)
-    y = window_reverse(yw, ph.window, gh, gw)
-    if ph.shift:
-        y = jnp.roll(y, (ph.shift, ph.shift), axis=(1, 2))
-    return y.reshape(b, t, c)
+    return _window_unfold(ph, yw, x.shape[0])
 
 
 def _stack_block_params(bps) -> Dict[str, Any]:
@@ -697,24 +707,13 @@ def _layer_group_phase(ph: Phase, params: Any, x: jax.Array, obs,
         return x
     sp = _stack_block_params([_subtree(params, m.path)
                               for m in ph.members])
-    b, t, c = x.shape
     if not ph.window:
         return _grouped_layer_call(ph, sp, x, obs, quantized, backend,
                                    None, None, shard)
-    gh, gw = ph.grid
-    xs = x.reshape(b, gh, gw, c)
-    if ph.shift:
-        xs = jnp.roll(xs, (-ph.shift, -ph.shift), axis=(1, 2))
-    xw = window_partition(xs, ph.window)                # (B*nW, n, C)
-    idx = jnp.asarray(rel_pos_index(ph.window))
-    bias = sp["rel_bias"][:, idx].transpose(0, 3, 1, 2)  # (L, H, n, n)
-    mask = jnp.asarray(shifted_window_mask(gh, gw, ph.window, ph.shift))
+    xw, bias, mask = _window_fold(ph, x, sp["rel_bias"])
     yw = _grouped_layer_call(ph, sp, xw, obs, quantized, backend,
                              bias, mask, shard)
-    y = window_reverse(yw, ph.window, gh, gw)
-    if ph.shift:
-        y = jnp.roll(y, (ph.shift, ph.shift), axis=(1, 2))
-    return y.reshape(b, t, c)
+    return _window_unfold(ph, yw, x.shape[0])
 
 
 def _fold_phase(ph: Phase, bp: Any, x: jax.Array, inner: jax.Array,

@@ -4,6 +4,7 @@ TNT through the same pipeline (inner/outer phases, the pixel batch-fold).
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -474,4 +475,53 @@ def test_phases_run_under_named_scopes(monkeypatch, fused):
                         lambda name: contextlib.nullcontext())
     plain = lower()
     assert "/layer0/" not in plain.as_text(debug_info=True)
+    assert scoped.as_text() == plain.as_text()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_window_fold_and_unfold_run_under_their_scopes(monkeypatch, fused):
+    """Each windowed phase's shift, window fold and relative-bias gather
+    carry ``<phase>/fold`` in their op metadata, and the unfold and
+    unshift ``<phase>/unfold``; the kernel's own operations carry
+    neither, and the scopes change no operation."""
+    import contextlib
+
+    cfg = dataclasses.replace(vision_registry.build_cfg(
+        "swin_t", backend="xla"), fused=fused)
+    sched = vision_registry.make_schedule(cfg)
+    windowed = [scope for ph, scope in zip(sched.phases,
+                                           sched_lib.phase_scopes(sched))
+                if ph.window]
+    assert windowed == ([f"layer{i}" for i in range(4)] if fused else
+                        [f"msa{i}" for i in range(4)])
+    assert any(ph.shift for ph in sched.phases)
+    params = swin.init_params(jax.random.PRNGKey(0), cfg)
+    patches = jax.ShapeDtypeStruct(
+        (2, (cfg.image // cfg.patch) ** 2, cfg.patch * cfg.patch * 3),
+        jnp.float32)
+
+    def lower():
+        return jax.jit(lambda p, x: swin.forward(p, x, cfg)).lower(
+            params, patches)
+
+    scoped = lower()
+    text = scoped.as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+
+    def where(op):
+        """The location of each operation ``op`` in the lowered text."""
+        return [locs[m] for m in re.findall(
+            rf"{op}\b.*loc\((#loc\d+)\)", text)]
+
+    for scope in windowed:
+        assert f"/{scope}/fold/gather" in text, scope
+        assert f"/{scope}/unfold/" in text, scope
+    # the softmax of the windowed attention is no glue
+    exps = where("stablehlo.exponential")
+    assert len(exps) == 4
+    assert not any("/fold/" in e or "/unfold/" in e for e in exps)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = lower()
+    assert "/fold/" not in plain.as_text(debug_info=True)
     assert scoped.as_text() == plain.as_text()

@@ -172,8 +172,8 @@ def _frozen_calibrator():
     return cal
 
 
-def _deit_t_shapes(sharding, mode):
-    cfg = vision_registry.build_cfg("deit_t", full=True, backend="pallas")
+def _full_shapes(name, sharding, mode="float"):
+    cfg = vision_registry.build_cfg(name, full=True, backend="pallas")
     params = jax.eval_shape(
         lambda: vision_registry.init_params(jax.random.PRNGKey(0), cfg))
     if mode == "int8":
@@ -186,7 +186,7 @@ def _deit_t_shapes(sharding, mode):
 @pytest.mark.parametrize("mode", ["float", "int8"])
 def test_deit_t_full_forward(one_chip, mode):
     """The whole served DeiT-Ti forward (224 px, 12 layers) on one chip."""
-    cfg, params = _deit_t_shapes(one_chip, mode)
+    cfg, params = _full_shapes("deit_t", one_chip, mode)
     obs = _frozen_calibrator() if mode == "int8" else None
 
     def fwd(p, images):
@@ -198,12 +198,27 @@ def test_deit_t_full_forward(one_chip, mode):
     assert text.count("tpu_custom_call") >= cfg.layers
 
 
+def test_swin_t_full_forward(one_chip):
+    """The whole served Swin-T float forward (224 px, depths 2/2/6/2,
+    widths 96-768) at bucket 32 on one chip: every layer a windowed
+    fused kernel over 32 x (64, 16, 4, 1) windows of 49 tokens."""
+    cfg, params = _full_shapes("swin_t", one_chip)
+
+    def fwd(p, images):
+        return vision_registry.forward_fn(cfg)(
+            p, vit.extract_patches(images, cfg.patch), cfg)
+
+    text = _compile(fwd, params, _spec(one_chip, (32, 224, 224, 3)))
+    # one fused layer kernel per block, none interpreted
+    assert text.count("tpu_custom_call") == sum(cfg.depths)
+
+
 @pytest.mark.parametrize("mode", ["float", "int8"])
 def test_deit_t_data_mesh_forward(topo, mode):
     """The 4-chip data-parallel forward: kernels run per shard under
     shard_map (GSPMD cannot partition a Mosaic kernel)."""
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
-    cfg, params = _deit_t_shapes(NamedSharding(mesh, P()), mode)
+    cfg, params = _full_shapes("deit_t", NamedSharding(mesh, P()), mode)
     sched = vision_registry.make_schedule(cfg)
     fn = sched_lib.build_sharded_fn(
         sched, params, mesh, batch=8,
