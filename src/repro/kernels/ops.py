@@ -26,6 +26,7 @@ from .head_attention import decode_attention as _decode_pallas
 from .head_attention import flash_attention as _flash_pallas
 from .int8_matmul import int8_matmul as _int8_pallas
 from .rglru_scan import rglru_scan as _rglru_pallas
+from .vita_layer import note_window_call as _note_window_call
 from .vita_layer import vita_layer as _vita_layer_pallas
 from .vita_layer import vita_layer_int8 as _vita_layer_int8_pallas
 from .vita_layer import vita_layer_group as _vita_layer_group_pallas
@@ -196,17 +197,16 @@ def vita_layer_fused(x, wq, wk, wv, w_msa, ln1_w, ln1_b, ln2_w, ln2_b,
                      msa_axis: Optional[str] = None,
                      mlp_axis: Optional[str] = None):
     """One fused encoder layer (msa -> concat -> mlp): (B, N, D) float ->
-    (B, N, D), a single kernel chain with no phase-boundary HBM round-trip.
+    (B, N, D), one kernel chain with no phase-boundary HBM round-trip.
     ``msa_axis``/``mlp_axis`` name the mesh axis to all-reduce the two
-    row-parallel partials over when called on local shards under
-    `shard_map` (xla backend only).
-    """
+    row-parallel partials over on local shards (xla backend only)."""
     if get_backend(backend) == "xla":
         return ref.vita_layer_ref(x, wq, wk, wv, w_msa, ln1_w, ln1_b,
                                   ln2_w, ln2_b, w_up, b_up, w_down, b_down,
                                   bias, mask, msa_axis=msa_axis,
                                   mlp_axis=mlp_axis)
     _no_pallas_collectives(msa_axis, mlp_axis)
+    _note_window_call(x, wq, w_up, mask)
     return _vita_layer_pallas(x, wq, wk, wv, w_msa, ln1_w, ln1_b,
                               ln2_w, ln2_b, w_up, b_up, w_down, b_down,
                               bias, mask, interpret=_interp())
@@ -252,6 +252,7 @@ def vita_layer_group(x, wq, wk, wv, w_msa, ln1_w, ln1_b, ln2_w, ln2_b,
                                         msa_axis=msa_axis,
                                         mlp_axis=mlp_axis)
     _no_pallas_collectives(msa_axis, mlp_axis)
+    _note_window_call(x, wq, w_up, mask)
     return _vita_layer_group_pallas(x, wq, wk, wv, w_msa, ln1_w, ln1_b,
                                     ln2_w, ln2_b, w_up, b_up, w_down,
                                     b_down, bias, mask, interpret=_interp())

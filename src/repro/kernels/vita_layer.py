@@ -38,21 +38,21 @@ float-accumulation order.  The int32 concat accumulator is requantized
 once at the tail (per-output-channel w_msa scales are head-invariant, so
 head slices may accumulate in int32).
 
-Windowed (Swin W-MSA) layers fuse too: the control program folds windows
-into the batch axis exactly as for `vita_msa`, and because LN, the concat
-projection, the residuals and the MLP are all per-token maps, the WHOLE
-layer commutes with the window permutation — the kernel runs on the
-(B·nW, n, C) layout and the executor reverses the fold afterwards.
+Windowed (Swin W-MSA) layers fuse too: the executor folds windows into the
+batch axis, and as LN, the concat, the residuals and the MLP are per-token
+maps, the layer commutes with the fold.  The float windowed calls, one
+layer or a group, have bodies and grids of their own: Wb windows a step.
 
-VMEM budget per grid step: x/acc/out tiles (3·N·D) + one head's weights
-(3·D·Dh + Dh·D) + the full MLP matrices (2·D·M, int8 in PTQ mode) + the
-per-step Q/K/V/S head working set.  Sized for the edge regime the paper
-targets (D ≤ ~384 comfortably; ViT-B at fp32 would need hidden chunking —
-see `fused_mlp.py` — before running un-interpreted on real hardware).
+VMEM a grid step: x/out blocks (two buffers each) + z/acc (N·D each) + a
+head's weights (3·D·Dh + Dh·D) + both MLP matrices (2·D·M, int8 in PTQ) +
+the head's Q/K/V/S.  Windowed, N is Wb·n and the (Wb·n, M) MLP hidden and
+Wb windows' scores join, and a group's Wb·n·D carry: `window_block` keeps
+a layer's step under 16 MiB by `_window_step_bytes` (Swin-T, Wb 8: 4–11 MiB).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -87,13 +87,13 @@ def _int8_dot(a_q, b_q):
 def _vita_layer_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
                        ln1w_ref, ln1b_ref, ln2w_ref, ln2b_ref,
                        wup_ref, bup_ref, wdown_ref, bdown_ref,
-                       *rest, scale: float, n_heads: int, windowed: bool):
-    if windowed:
-        b_ref, m_ref, o_ref, z_ref, acc_ref = rest
-        extra = b_ref[0] + m_ref[0]
-    else:
-        o_ref, z_ref, acc_ref = rest
-        extra = None
+                       o_ref, z_ref, acc_ref, *, scale: float, n_heads: int):
+    # One image's N tokens a grid step, on the (B, H) grid of the module
+    # docstring, with no relative bias or region mask (ViT, DeiT).  A
+    # windowed (Swin) layer runs `_vita_layer_windowed_kernel` on a grid
+    # of its own, where a block of Wb windows shares each step and its
+    # head's weights: its head matmuls see Wb·n rows where these see N.
+    extra = None
     j = pl.program_id(1)
     x = x_ref[0]
 
@@ -137,9 +137,9 @@ def vita_layer(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
 
     wq/wk/wv: (H, D, Dh); w_msa: (D, D) (head-major rows, sliced per head
     inside); w_up: (D, M); w_down: (M, D).  Windowed (Swin) mode takes
-    ``bias`` (H, n, n) + ``mask`` (nW, n, n) exactly as `vita_msa_batched`
-    — the caller folds windows into the batch axis and reverses after.
-    """
+    ``bias`` (H, n, n) + ``mask`` (nW, n, n) as `vita_msa_batched` does —
+    the caller folds windows into the batch axis and reverses after — and
+    runs `window_block` windows a grid step (`_vita_layer_windowed`)."""
     if (bias is None) != (mask is None):
         raise ValueError("windowed mode needs both bias and mask")
     b, n, d = x.shape
@@ -160,16 +160,16 @@ def vita_layer(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
     ]
     operands = [x, wq, wk, wv, wmsa_h, ln1_w, ln1_b, ln2_w, ln2_b,
                 w_up, b_up, w_down, b_down]
-    windowed = bias is not None
-    if windowed:
-        n_w = mask.shape[0]
-        in_specs += [
-            pl.BlockSpec((1, n, n), lambda i, j: (j, 0, 0)),       # rel bias
-            pl.BlockSpec((1, n, n), lambda i, j: (i % n_w, 0, 0)),  # region
-        ]
-        operands += [bias.astype(jnp.float32), mask.astype(jnp.float32)]
+    if bias is not None:
+        # Swin W-MSA: a block of windows a grid step, on a body and grid
+        # of its own (`window_block` sizes the block); the specs above
+        # serve the unwindowed call alone.
+        return _vita_layer_windowed(x, wq, wk, wv, wmsa_h, ln1_w, ln1_b,
+                                    ln2_w, ln2_b, w_up, b_up, w_down,
+                                    b_down, bias, mask, interpret=interpret)
+
     kernel = functools.partial(_vita_layer_kernel, scale=dh ** -0.5,
-                               n_heads=h, windowed=windowed)
+                               n_heads=h)
     return pl.pallas_call(
         kernel,
         grid=(b, h),
@@ -182,6 +182,200 @@ def vita_layer(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
+
+
+# ---------------------------------------------------------------------------
+# windowed float kernel (Swin W-MSA): a block of windows per grid step
+# ---------------------------------------------------------------------------
+
+# Rows (windows × tokens) a windowed grid step aims for, and the scoped
+# VMEM a step may take, by `_window_step_bytes` (16 MiB: the limit the
+# TPU compiler gives a kernel by default); see `window_block`.
+WINDOW_ROWS = 392
+WINDOW_VMEM_BYTES = 16 << 20
+
+
+def _window_step_bytes(wb: int, n: int, d: int, m: int) -> int:
+    """Scoped VMEM of a windowed grid step of ``wb`` windows, ``n`` tokens
+    each: per window sixteen (n, 128-lane) float32 tiles of the head's
+    q/k/v, scores and probabilities, and per token 8·(D + M) bytes of
+    x/out blocks, z/acc scratch and the MLP hidden.  An upper bound of
+    what the v5e compiler asks at Swin-T's four stages (its ask at
+    Wb 8: 4.7, 4.2, 5.5 and 11.2 MiB; at Wb 16: 9.8, 8.8, 11.9, 26.4)."""
+    tile = 4 * -(-n // 8) * 8 * 128
+    return wb * (16 * tile + 8 * n * (d + m))
+
+
+def window_block(n_windows: int, n_w: int, n: int, d: int, m: int) -> int:
+    """Windows per grid step of the windowed `vita_layer`, from the shapes
+    it is called with: ``n_windows`` = B·nW folded windows, ``n_w`` of
+    them an image, ``n`` tokens a window, width ``d``, MLP hidden ``m``.
+
+    The block divides the folded count and lines up with the masks'
+    period (it divides ``n_w``, or ``n_w`` divides it), so every block
+    reads its masks as one (Wb, n, n) block.  Of those, the smallest whose
+    Wb·n rows reach `WINDOW_ROWS`, else the largest; either within
+    `WINDOW_VMEM_BYTES`.
+    """
+    fits = [wb for wb in range(1, n_windows + 1)
+            if n_windows % wb == 0 and (n_w % wb == 0 or wb % n_w == 0)
+            and _window_step_bytes(wb, n, d, m) <= WINDOW_VMEM_BYTES]
+    reach = [wb for wb in fits if wb * n >= WINDOW_ROWS]
+    return min(reach) if reach else max(fits, default=1)
+
+
+def window_grid_steps(n_windows: int, n_w: int, n: int, d: int, h: int,
+                      m: int) -> int:
+    """Grid steps of one windowed `vita_layer` call: (B·nW / Wb) × H."""
+    return n_windows // window_block(n_windows, n_w, n, d, m) * h
+
+
+_step_tallies: list = []
+
+
+@contextlib.contextmanager
+def tally_window_steps():
+    """Count the grid steps of the windowed `vita_layer` calls traced
+    inside: yields a one-item list that holds the running count."""
+    tally = [0]
+    _step_tallies.append(tally)
+    try:
+        yield tally
+    finally:
+        _step_tallies.remove(tally)
+
+
+def note_window_call(x, wq, w_up, mask) -> None:
+    """Add one `vita_layer` or `vita_layer_group` call's grid steps to the
+    open tallies (none for an unwindowed call, ``mask`` None).  The caller
+    notes each call as it makes it: jit traces the kernel's wrapper once
+    per shape, and a forward calls it with one shape for several layers."""
+    if mask is None:
+        return
+    bw, n, d = x.shape
+    layers = wq.shape[0] if wq.ndim == 4 else 1     # (L, H, D, Dh): a group
+    steps = layers * window_grid_steps(bw, mask.shape[0], n, d,
+                                       wq.shape[-3], w_up.shape[-1])
+    for tally in _step_tallies:
+        tally[0] += steps
+
+
+def _window_head(z, wq, wk, wv, wmsa, bias, m_ref, *, wb: int, n: int,
+                 scale: float):
+    """One head's concat-slice contribution for a block of ``wb`` windows
+    of ``n`` tokens: z (Wb·n, D) -> (Wb·n, D).  Engine 2 runs window by
+    window: one (n, n) score tile each, the head's relative ``bias``
+    shared by all of them, each window's region mask (row w of
+    ``m_ref``) its own.  `_softmax_av` on each window keeps the per-layer
+    and the layer-group kernels bit for bit alike (a batched q·kᵀ rounds
+    differently on the CPU)."""
+    def project(w):
+        return jnp.dot(z, w, preferred_element_type=jnp.float32
+                       ).reshape(wb, n, -1)
+
+    q, k, v = project(wq), project(wk), project(wv)
+    sa = jnp.stack([_softmax_av(q[w], k[w], v[w], scale=scale,
+                                extra=bias + m_ref[w]) for w in range(wb)])
+    return jnp.dot(sa.reshape(wb * n, -1), wmsa,
+                   preferred_element_type=jnp.float32)
+
+
+def _mlp_tail(h1, ln2_w, ln2_b, w_up, b_up, w_down, b_down):
+    """LN2 -> MLP -> residual on the post-MSA rows h1."""
+    z2 = _ln(h1, ln2_w, ln2_b)
+    hid = jax.nn.gelu(jnp.dot(z2, w_up, preferred_element_type=jnp.float32)
+                      + b_up.astype(jnp.float32))
+    return h1 + jnp.dot(hid, w_down, preferred_element_type=jnp.float32) \
+        + b_down.astype(jnp.float32)
+
+
+def _window_rows(x_ref):
+    """A (Wb, n, D) block of windows as one (Wb·n, D) float32 matrix (a
+    bfloat16 reshape would split the packed sublane tile)."""
+    wb, n, d = x_ref.shape
+    return x_ref[...].astype(jnp.float32).reshape(wb * n, d)
+
+
+def _vita_layer_windowed_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
+                                ln1w_ref, ln1b_ref, ln2w_ref, ln2b_ref,
+                                wup_ref, bup_ref, wdown_ref, bdown_ref,
+                                b_ref, m_ref, o_ref, z_ref, acc_ref, *,
+                                scale: float, n_heads: int):
+    j = pl.program_id(1)
+    wb, n, d = x_ref.shape
+
+    @pl.when(j == 0)
+    def _init():
+        z_ref[...] = _ln(_window_rows(x_ref), ln1w_ref[...], ln1b_ref[...])
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += _window_head(z_ref[...], wq_ref[0], wk_ref[0],
+                                 wv_ref[0], wmsa_ref[0], b_ref[0], m_ref,
+                                 wb=wb, n=n, scale=scale)
+
+    @pl.when(j == n_heads - 1)
+    def _tail():
+        y = _mlp_tail(_window_rows(x_ref) + acc_ref[...], ln2w_ref[...],
+                      ln2b_ref[...], wup_ref[...], bup_ref[...],
+                      wdown_ref[...], bdown_ref[...])
+        o_ref[...] = y.reshape(wb, n, d).astype(o_ref.dtype)
+
+
+def _window_masks(mask, wb: int):
+    """The (nW, n, n) region masks as float32 blocks of ``wb``, tiled to
+    ``wb`` where nW < Wb, and the number of such blocks: window i of the
+    fold takes mask i % nW, so block i takes mask block i % (nW / Wb)."""
+    mask = mask.astype(jnp.float32)
+    if mask.shape[0] < wb:
+        mask = jnp.tile(mask, (wb // mask.shape[0], 1, 1))
+    return mask, mask.shape[0] // wb
+
+
+def _vita_layer_windowed(x, wq, wk, wv, wmsa_h, ln1_w, ln1_b, ln2_w, ln2_b,
+                         w_up, b_up, w_down, b_down, bias, mask, *,
+                         interpret: bool, wb: int | None = None):
+    """`vita_layer` in windowed mode at ``wb`` windows per grid step
+    (`window_block`'s by default): grid (B·nW / Wb, H), heads minor as in
+    the unwindowed call."""
+    bw, n, d = x.shape
+    h, _, dh = wq.shape
+    m = w_up.shape[1]
+    n_w = mask.shape[0]
+    if wb is None:
+        wb = window_block(bw, n_w, n, d, m)
+    if bw % wb or (n_w % wb and wb % n_w):
+        raise ValueError(f"{wb} windows a step neither divide {bw} folded "
+                         f"windows nor line up with {n_w} masks")
+    mask, n_mb = _window_masks(mask, wb)
+    w_spec = pl.BlockSpec((1, d, dh), lambda i, j: (j, 0, 0))
+    vec_d = pl.BlockSpec((d,), lambda i, j: (0,))
+    x_spec = pl.BlockSpec((wb, n, d), lambda i, j: (i, 0, 0))
+    kernel = functools.partial(_vita_layer_windowed_kernel,
+                               scale=dh ** -0.5, n_heads=h)
+    return pl.pallas_call(
+        kernel,
+        grid=(bw // wb, h),
+        in_specs=[
+            x_spec,
+            w_spec, w_spec, w_spec,
+            pl.BlockSpec((1, dh, d), lambda i, j: (j, 0, 0)),   # concat
+            vec_d, vec_d, vec_d, vec_d,
+            pl.BlockSpec((d, m), lambda i, j: (0, 0)),          # w_up
+            pl.BlockSpec((m,), lambda i, j: (0,)),
+            pl.BlockSpec((m, d), lambda i, j: (0, 0)),          # w_down
+            vec_d,
+            pl.BlockSpec((1, n, n), lambda i, j: (j, 0, 0)),    # rel bias
+            pl.BlockSpec((wb, n, n), lambda i, j: (i % n_mb, 0, 0)),
+        ],
+        out_specs=x_spec,
+        out_shape=jax.ShapeDtypeStruct((bw, n, d), x.dtype),
+        scratch_shapes=[pltpu.VMEM((wb * n, d), jnp.float32),   # z
+                        pltpu.VMEM((wb * n, d), jnp.float32)],  # acc
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(x, wq, wk, wv, wmsa_h, ln1_w, ln1_b, ln2_w, ln2_b,
+      w_up, b_up, w_down, b_down, bias.astype(jnp.float32), mask)
 
 
 # ---------------------------------------------------------------------------
@@ -204,14 +398,10 @@ def _layer_vecs(*vecs):
 def _vita_layer_group_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
                              ln1w_ref, ln1b_ref, ln2w_ref, ln2b_ref,
                              wup_ref, bup_ref, wdown_ref, bdown_ref,
-                             *rest, scale: float, n_layers: int,
-                             n_heads: int, windowed: bool):
-    if windowed:
-        b_ref, m_ref, o_ref, y_ref, z_ref, acc_ref = rest
-        extra = b_ref[0, 0] + m_ref[0]
-    else:
-        o_ref, y_ref, z_ref, acc_ref = rest
-        extra = None
+                             o_ref, y_ref, z_ref, acc_ref, *, scale: float,
+                             n_layers: int, n_heads: int):
+    # One image a grid step, unwindowed; a windowed group runs
+    # `_vita_layer_group_windowed_kernel`.
     l = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -234,7 +424,7 @@ def _vita_layer_group_kernel(x_ref, wq_ref, wk_ref, wv_ref, wmsa_ref,
     q = jnp.dot(z, wq_ref[0, 0], preferred_element_type=jnp.float32)
     k = jnp.dot(z, wk_ref[0, 0], preferred_element_type=jnp.float32)
     v = jnp.dot(z, wv_ref[0, 0], preferred_element_type=jnp.float32)
-    sa = _softmax_av(q, k, v, scale=scale, extra=extra)
+    sa = _softmax_av(q, k, v, scale=scale)
     acc_ref[...] += jnp.dot(sa, wmsa_ref[0, 0],
                             preferred_element_type=jnp.float32)
 
@@ -299,16 +489,13 @@ def vita_layer_group(x: jax.Array, wq: jax.Array, wk: jax.Array,
     operands = [x, wq, wk, wv, wmsa_h,
                 *_layer_vecs(ln1_w, ln1_b, ln2_w, ln2_b),
                 w_up, *_layer_vecs(b_up), w_down, *_layer_vecs(b_down)]
-    windowed = bias is not None
-    if windowed:
-        n_w = mask.shape[0]
-        in_specs += [
-            pl.BlockSpec((1, 1, n, n), lambda i, l, j: (l, j, 0, 0)),
-            pl.BlockSpec((1, n, n), lambda i, l, j: (i % n_w, 0, 0)),
-        ]
-        operands += [bias.astype(jnp.float32), mask.astype(jnp.float32)]
+    if bias is not None:
+        return _vita_layer_group_windowed(x, wq, wk, wv, wmsa_h, ln1_w,
+                                          ln1_b, ln2_w, ln2_b, w_up, b_up,
+                                          w_down, b_down, bias, mask,
+                                          interpret=interpret)
     kernel = functools.partial(_vita_layer_group_kernel, scale=dh ** -0.5,
-                               n_layers=n_l, n_heads=h, windowed=windowed)
+                               n_layers=n_l, n_heads=h)
     return pl.pallas_call(
         kernel,
         grid=(b, n_l, h),
@@ -322,6 +509,84 @@ def vita_layer_group(x: jax.Array, wq: jax.Array, wk: jax.Array,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
+
+
+def _vita_layer_group_windowed_kernel(x_ref, wq_ref, wk_ref, wv_ref,
+                                      wmsa_ref, ln1w_ref, ln1b_ref,
+                                      ln2w_ref, ln2b_ref, wup_ref, bup_ref,
+                                      wdown_ref, bdown_ref, b_ref, m_ref,
+                                      o_ref, y_ref, z_ref, acc_ref, *,
+                                      scale: float, n_layers: int,
+                                      n_heads: int):
+    # The per-layer windowed step with the group's layer axis: the block's
+    # (Wb·n, D) activation rides in y_ref across the L layers.
+    l = pl.program_id(1)
+    j = pl.program_id(2)
+    wb, n, d = x_ref.shape
+
+    @pl.when((l == 0) & (j == 0))
+    def _load():
+        y_ref[...] = _window_rows(x_ref)
+
+    @pl.when(j == 0)
+    def _init():
+        z_ref[...] = _ln(y_ref[...], ln1w_ref[0], ln1b_ref[0])
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += _window_head(z_ref[...], wq_ref[0, 0], wk_ref[0, 0],
+                                 wv_ref[0, 0], wmsa_ref[0, 0], b_ref[0, 0],
+                                 m_ref, wb=wb, n=n, scale=scale)
+
+    @pl.when(j == n_heads - 1)
+    def _tail():
+        y_ref[...] = _mlp_tail(y_ref[...] + acc_ref[...], ln2w_ref[0],
+                               ln2b_ref[0], wup_ref[0], bup_ref[0],
+                               wdown_ref[0], bdown_ref[0])
+
+    @pl.when((l == n_layers - 1) & (j == n_heads - 1))
+    def _out():
+        o_ref[...] = y_ref[...].reshape(wb, n, d).astype(o_ref.dtype)
+
+
+def _vita_layer_group_windowed(x, wq, wk, wv, wmsa_h, ln1_w, ln1_b, ln2_w,
+                               ln2_b, w_up, b_up, w_down, b_down, bias,
+                               mask, *, interpret: bool):
+    """`vita_layer_group` in windowed mode: the per-layer call's block of
+    `window_block` windows a grid step, on grid (B·nW / Wb, L, H)."""
+    bw, n, d = x.shape
+    n_l, h, _, dh = wq.shape
+    m = w_up.shape[2]
+    wb = window_block(bw, mask.shape[0], n, d, m)
+    mask, n_mb = _window_masks(mask, wb)
+    w_spec = pl.BlockSpec((1, 1, d, dh), lambda i, l, j: (l, j, 0, 0))
+    vec_d, vec_m = _layer_vec_spec(d), _layer_vec_spec(m)
+    x_spec = pl.BlockSpec((wb, n, d), lambda i, l, j: (i, 0, 0))
+    kernel = functools.partial(_vita_layer_group_windowed_kernel,
+                               scale=dh ** -0.5, n_layers=n_l, n_heads=h)
+    return pl.pallas_call(
+        kernel,
+        grid=(bw // wb, n_l, h),
+        in_specs=[
+            x_spec,
+            w_spec, w_spec, w_spec,
+            pl.BlockSpec((1, 1, dh, d), lambda i, l, j: (l, j, 0, 0)),
+            vec_d, vec_d, vec_d, vec_d,
+            pl.BlockSpec((1, d, m), lambda i, l, j: (l, 0, 0)),
+            vec_m,
+            pl.BlockSpec((1, m, d), lambda i, l, j: (l, 0, 0)),
+            vec_d,
+            pl.BlockSpec((1, 1, n, n), lambda i, l, j: (l, j, 0, 0)),
+            pl.BlockSpec((wb, n, n), lambda i, l, j: (i % n_mb, 0, 0)),
+        ],
+        out_specs=x_spec,
+        out_shape=jax.ShapeDtypeStruct((bw, n, d), x.dtype),
+        scratch_shapes=[pltpu.VMEM((wb * n, d), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(x, wq, wk, wv, wmsa_h, *_layer_vecs(ln1_w, ln1_b, ln2_w, ln2_b),
+      w_up, *_layer_vecs(b_up), w_down, *_layer_vecs(b_down),
+      bias.astype(jnp.float32), mask)
 
 
 # ---------------------------------------------------------------------------

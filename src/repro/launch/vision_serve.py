@@ -78,6 +78,7 @@ from repro.core import spans
 from repro.core.quant import Calibrator
 from repro.core.schedule import FusionPolicy
 from repro.distributed import sharding as shd
+from repro.kernels.vita_layer import tally_window_steps
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vision_registry, vit
 
@@ -387,6 +388,10 @@ class VisionServer:
         self.n_stack_allocated = 0
         self._rid = 0
         self._forwards: Dict[Tuple, callable] = {}
+        # Grid steps of the windowed `vita_layer` kernel in one forward,
+        # per bucket, counted as the bucket's forward is traced (0 where
+        # no layer is windowed); reported by `run` as ``window_steps``.
+        self.window_steps: Dict[int, int] = {}
 
     @property
     def mesh_shape(self) -> str:
@@ -431,13 +436,24 @@ class VisionServer:
             # MLP-column shards and the executor psums at the two
             # residual re-entries.
             sched = vision_registry.make_schedule(cfg)
-            fn = jax.jit(sched_lib.build_sharded_fn(
+            fwd = sched_lib.build_sharded_fn(
                 sched, self.served_params, self.mesh, batch=bucket,
-                observer=obs, preprocess=patchify, x_ndim=4))
+                observer=obs, preprocess=patchify, x_ndim=4)
         else:
             model_fwd = vision_registry.forward_fn(cfg)
-            fn = jax.jit(lambda p, images: model_fwd(
-                p, patchify(images), cfg, observer=obs))
+
+            def fwd(p, images):
+                return model_fwd(p, patchify(images), cfg, observer=obs)
+
+        def forward(p, images):
+            # Runs once per bucket, as jit traces it: the count of the
+            # windowed kernel's grid steps is known then.
+            with tally_window_steps() as steps:
+                out = fwd(p, images)
+            self.window_steps[int(images.shape[0])] = steps[0]
+            return out
+
+        fn = jax.jit(forward)
         self._forwards[key] = fn
         return fn
 
@@ -696,6 +712,8 @@ class VisionServer:
             "group_buckets": {str(b): int(g)
                               for b, g in sorted(
                                   self._bucket_group.items())},
+            "window_steps": {str(b): int(n) for b, n in
+                             sorted(self.window_steps.items())},
             "batches": self.n_batches - batches0,
             "padded": self.n_padded - padded0,
             "wall_s": dt,

@@ -10,7 +10,11 @@ from repro.kernels import ref
 from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.head_attention import decode_attention, flash_attention
 from repro.kernels.int8_matmul import int8_matmul
-from repro.kernels.vita_layer import vita_layer, vita_layer_int8
+from repro.core.schedule import shifted_window_mask
+from repro.kernels.vita_layer import (WINDOW_VMEM_BYTES, _vita_layer_windowed,
+                                      _window_step_bytes, tally_window_steps,
+                                      vita_layer, vita_layer_int8,
+                                      window_block, window_grid_steps)
 from repro.kernels.vita_msa import vita_msa, vita_msa_batched, vita_msa_int8
 
 
@@ -431,16 +435,121 @@ def test_vita_layer_matches_the_unfused_composition():
     np.testing.assert_allclose(out, want, rtol=3e-5, atol=3e-5)
 
 
-def test_vita_layer_windowed_matches_ref():
-    b, n_w, n, d, h, m = 2, 4, 49, 48, 3, 96
+def _random_mask(key, n_w, n):
+    keep = jax.random.bernoulli(key, 0.8, (n_w, n, n))
+    return jnp.where(keep | jnp.eye(n, dtype=bool)[None], 0.0, -1e30)
+
+
+# (images B, windows an image nW, the block `window_block` picks, mask):
+# a block of 8 over 4 masks, tiled; 16 masks read two blocks apart
+# (window i's mask at phase i % (nW / Wb)); one window an image, so a
+# block spans 8 images; 12 windows, which 8 does not divide; nine masks
+# of a 21 x 21 grid; the shifted-window mask of a 14 x 14 grid.
+WINDOWED_CASES = {
+    "nw4_tiled": (2, 4, 8, "random"),
+    "nw16_phase": (2, 16, 8, "random"),
+    "nw1_across_images": (8, 1, 8, "random"),
+    "fold12_divisor": (3, 4, 12, "random"),
+    "nw9": (2, 9, 9, "random"),
+    "shifted_14x14": (2, 4, 8, "shifted"),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOWED_CASES))
+def test_vita_layer_windowed_matches_ref(case):
+    b, n_w, wb, mask_kind = WINDOWED_CASES[case]
+    n, d, h, m = 49, 48, 3, 96
+    assert window_block(b * n_w, n_w, n, d, m) == wb
     x, ws, w_msa, lns, mlp = _layer_problem(jax.random.PRNGKey(43),
                                             b * n_w, n, d, h, m)
     bias = rand(jax.random.PRNGKey(44), (h, n, n), scale=0.5)
-    keep = jax.random.bernoulli(jax.random.PRNGKey(45), 0.8, (n_w, n, n))
-    mask = jnp.where(keep | jnp.eye(n, dtype=bool)[None], 0.0, -1e30)
+    if mask_kind == "shifted":
+        mask = jnp.asarray(shifted_window_mask(14, 14, 7, 3))
+    else:
+        mask = _random_mask(jax.random.PRNGKey(45), n_w, n)
     out = vita_layer(x, *ws, w_msa, *lns, *mlp, bias, mask, interpret=True)
     expect = ref.vita_layer_ref(x, *ws, w_msa, *lns, *mlp, bias, mask)
     np.testing.assert_allclose(out, expect, rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("wb", [1, 2, 4])
+def test_vita_layer_windowed_any_aligned_block(wb):
+    """Every block that lines up with the masks computes the same layer:
+    with 8 masks and blocks of 1, 2 or 4, block i reads mask block
+    i % (8 / Wb)."""
+    b, n_w, n, d, h, m = 2, 8, 16, 24, 2, 48
+    x, ws, w_msa, lns, mlp = _layer_problem(jax.random.PRNGKey(51),
+                                            b * n_w, n, d, h, m)
+    bias = rand(jax.random.PRNGKey(52), (h, n, n), scale=0.5)
+    mask = _random_mask(jax.random.PRNGKey(53), n_w, n)
+    out = _vita_layer_windowed(x, *ws, w_msa.reshape(h, d // h, d), *lns,
+                               *mlp, bias, mask, interpret=True, wb=wb)
+    expect = ref.vita_layer_ref(x, *ws, w_msa, *lns, *mlp, bias, mask)
+    np.testing.assert_allclose(out, expect, rtol=3e-5, atol=3e-5)
+
+
+def test_vita_layer_windowed_refuses_misaligned_block():
+    x, ws, w_msa, lns, mlp = _layer_problem(jax.random.PRNGKey(54),
+                                            12, 16, 24, 2, 48)
+    bias = jnp.zeros((2, 16, 16))
+    with pytest.raises(ValueError, match="line up"):
+        _vita_layer_windowed(x, *ws, w_msa.reshape(2, 12, 24), *lns, *mlp,
+                             bias, jnp.zeros((4, 16, 16)), interpret=True,
+                             wb=3)
+
+
+# Swin-T's stages: (windows an image nW, width D, heads H); 49 tokens,
+# MLP 4·D, bucket 32.
+SWIN_T_STAGES = ((64, 96, 3), (16, 192, 6), (4, 384, 12), (1, 768, 24))
+
+
+@pytest.mark.parametrize("stage", range(4))
+def test_window_block_groups_windows_at_swin_t_stages(stage):
+    n_w, d, h = SWIN_T_STAGES[stage]
+    wb = window_block(32 * n_w, n_w, 49, d, 4 * d)
+    assert wb > 1
+    assert (32 * n_w) % wb == 0 and (n_w % wb == 0 or wb % n_w == 0)
+    assert window_grid_steps(32 * n_w, n_w, 49, d, h, 4 * d) \
+        == 32 * n_w // wb * h
+
+
+def test_window_block_falls_back_to_largest_aligned_divisor():
+    # 6 windows of 49 tokens never reach the row target: the largest
+    # block that divides 6 and lines up with 2 masks is 6 (3 is not).
+    assert window_block(6, 2, 49, 48, 96) == 6
+    assert window_block(3, 3, 49, 48, 96) == 3
+    # a width whose blocks outgrow the VMEM budget stops short of it
+    wide = window_block(4096, 64, 49, 4096, 16384)
+    assert _window_step_bytes(wide, 49, 4096, 16384) <= WINDOW_VMEM_BYTES
+    assert _window_step_bytes(2 * wide, 49, 4096, 16384) \
+        > WINDOW_VMEM_BYTES
+
+
+def test_window_call_tally_counts_every_traced_call():
+    """`tally_window_steps` counts each windowed call made while it is
+    open, the second call of a shape too (jit traces the body once), and
+    a layer group's steps for each of its layers."""
+    from repro.kernels import ops
+    b, n_w, n, d, h, m = 2, 4, 16, 24, 2, 48
+    x, ws, w_msa, lns, mlp = _layer_problem(jax.random.PRNGKey(55),
+                                            b * n_w, n, d, h, m)
+    bias = jnp.zeros((h, n, n))
+    mask = jnp.zeros((n_w, n, n))
+
+    def two_layers(x):
+        for _ in range(2):
+            x = ops.vita_layer_fused(x, *ws, w_msa, *lns, *mlp, bias, mask,
+                                     backend="pallas")
+        pair = [jnp.stack([t, t]) for t in (*ws, w_msa, *lns, *mlp, bias)]
+        x = ops.vita_layer_group(x, *pair, mask, backend="pallas")
+        return ops.vita_layer_fused(x, *ws, w_msa, *lns, *mlp,
+                                    backend="pallas")    # unwindowed
+
+    with tally_window_steps() as steps:
+        jax.eval_shape(two_layers, x)
+    assert steps[0] == 4 * window_grid_steps(b * n_w, n_w, n, d, h, m)
+    jax.eval_shape(lambda y: two_layers(y), x)   # traced, but untallied
+    assert steps[0] == 4 * window_grid_steps(b * n_w, n_w, n, d, h, m)
 
 
 def _int8_layer_problem(key, b, n, d, h, m):

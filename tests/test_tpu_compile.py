@@ -120,11 +120,24 @@ def test_vita_msa_batched_deit_t(one_chip):
              interpret=False)
 
 
+# Swin-T's four stages as (dims, windows an image), each compiled at the
+# folded window count of bucket 32 and of bucket 1: the windowed call
+# picks its block of windows (`window_block`) from those shapes.
+SWIN_T_STAGES = ((SWIN_T_S0, 64), (dict(n=49, d=192, h=6, m=768), 16),
+                 (dict(n=49, d=384, h=12, m=1536), 4),
+                 (dict(n=49, d=768, h=24, m=3072), 1))
+WINDOWED = [(dims, bucket * n_w, n_w) for dims, n_w in SWIN_T_STAGES
+            for bucket in (32, 1)]
+WINDOWED_IDS = [f"swin_t_stage{i}_bucket{bucket}_windowed"
+                for i in range(4) for bucket in (32, 1)]
+
+
 @pytest.mark.parametrize("dims,batch,windows", [
     (DEIT_T, 8, 0),
     (SWIN_T_S0, 2 * 64, 64),
     (TNT_S_INNER, 2 * 196, 0),
-], ids=["deit_t", "swin_t_stage0_windowed", "tnt_s_inner"])
+    *WINDOWED,
+], ids=["deit_t", "swin_t_stage0_windowed", "tnt_s_inner", *WINDOWED_IDS])
 def test_vita_layer(one_chip, dims, batch, windows):
     s = one_chip
     n, d, h, m = dims["n"], dims["d"], dims["h"], dims["m"]
@@ -154,6 +167,19 @@ def test_vita_layer_group_deit_t(one_chip):
     n, d, h, m = DEIT_T["n"], DEIT_T["d"], DEIT_T["h"], DEIT_T["m"]
     args = (_layer_args(s, 8, n, d, h, m, layers=2)
             + _float_tail(s, d, m, lead=(2,)))
+    _compile(vita_layer_group, *args, interpret=False)
+
+
+@pytest.mark.parametrize("stage", range(4))
+def test_vita_layer_group_swin_t_windowed(one_chip, stage):
+    """Two windowed layers in one call at each Swin-T stage, bucket 32:
+    the per-layer call's block of windows plus the group's carry."""
+    s = one_chip
+    dims, n_w = SWIN_T_STAGES[stage]
+    n, d, h, m = dims["n"], dims["d"], dims["h"], dims["m"]
+    args = (_layer_args(s, 32 * n_w, n, d, h, m, layers=2)
+            + _float_tail(s, d, m, lead=(2,))
+            + [_spec(s, (2, h, n, n)), _spec(s, (n_w, n, n))])
     _compile(vita_layer_group, *args, interpret=False)
 
 

@@ -344,3 +344,34 @@ def test_stack_buffer_per_image_shape_and_dtype(tiny_setup, odd):
     assert again.images is buf
     assert (server.n_stack_allocated, server.n_stack_reused) == (2, 1)
     server.complete(again)
+
+
+def test_stats_report_windowed_kernel_steps_per_bucket():
+    """Each compiled bucket's forward counts the windowed kernel's grid
+    steps as jit traces it: per stage, (B·nW / Wb) × H a layer; a model
+    with no windowed layer counts none."""
+    from repro.kernels.vita_layer import window_grid_steps
+    cfg = vision_registry.build_cfg("swin_t", backend="pallas")
+    params = vision_registry.init_params(jax.random.PRNGKey(0), cfg)
+    server = VisionServer(cfg, params, serve_cfg=ServeConfig(buckets=(1, 2)))
+    for bucket in server.buckets:
+        server.compile_bucket(bucket)
+
+    def expect(bucket):
+        total, grid = 0, cfg.image // cfg.patch
+        for s, (depth, heads) in enumerate(zip(cfg.depths, cfg.heads)):
+            side, d = grid >> s, cfg.embed_dim << s
+            n_w = (side // cfg.window) ** 2
+            total += depth * window_grid_steps(
+                bucket * n_w, n_w, cfg.window ** 2, d, heads,
+                int(d * cfg.mlp_ratio))
+        return total
+
+    assert server.run()["window_steps"] == {"1": expect(1), "2": expect(2)}
+    assert expect(2) < 2 * expect(1)        # the block grows with the fold
+    vit_cfg = vision_registry.build_cfg("vit_edge", backend="pallas")
+    vit_server = VisionServer(
+        vit_cfg, vision_registry.init_params(jax.random.PRNGKey(0), vit_cfg),
+        serve_cfg=ServeConfig(buckets=(1,)))
+    vit_server.compile_bucket(1)
+    assert vit_server.run()["window_steps"] == {"1": 0}
